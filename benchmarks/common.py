@@ -18,6 +18,8 @@ tables the paper's figures share, and the one-line CSV emitter.
 """
 from __future__ import annotations
 
+import os
+from pathlib import Path
 
 from repro.core.types import (CHAMELEON, CLOUDLAB, DIDCLAB, LARGE_FILES,
                               MEDIUM_FILES, MIXED, SMALL_FILES)
@@ -46,3 +48,25 @@ def emit(name: str, seconds: float, derived) -> str:
     row = f"{name},{seconds * 1e6:.0f},{derived}"
     print(row, flush=True)
     return row
+
+
+#: Where compiled programs persist between runs when nothing else says so:
+#: a fixed path (it is part of every entry's key, so it must not move).
+COMPILE_CACHE_DIR = Path(__file__).resolve().parent.parent / ".jax_cache"
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for this process.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing else is set here; otherwise the cache is the repository's
+    ``.jax_cache/``.  Call before the first compile.  Returns the
+    directory in use.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    return str(COMPILE_CACHE_DIR)

@@ -25,6 +25,8 @@ import platform
 import sys
 import time
 
+from .common import use_compile_cache
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
@@ -38,6 +40,7 @@ def main() -> None:
                          "+ Report payloads)")
     args = ap.parse_args()
     only = set(args.only.split(","))
+    use_compile_cache()
 
     print("name,us_per_call,derived")
     summary = {}

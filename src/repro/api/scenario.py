@@ -9,9 +9,10 @@ grid becomes a handful of compiled executables instead of 72 sequential jit
 calls — and each executable stops scanning as soon as every lane of its
 batch has drained, instead of burning the full padded ``total_s`` horizon.
 
-On hosts with more than one accelerator device, groups are additionally
-sharded across devices: the stacked batch is padded to a multiple of the
-device count (:func:`repro.distributed.sharding.pad_batch`), placed with a
+On hosts with more than one accelerator device, groups with at least one
+lane per device are additionally sharded across devices: the stacked batch
+is padded to a multiple of the device count
+(:func:`repro.distributed.sharding.pad_batch`), placed with a
 ``batch``-sharded layout, and run through a ``shard_map``-wrapped runner
 whose input buffers are donated.  Each device early-exits on its own shard
 independently.
@@ -50,9 +51,9 @@ class Scenario:
     transfer are invariant to how generous the horizon was.
 
     ``executor`` selects the engine lowering (``repro.core.engine``):
-    ``"auto"`` (the default) resolves per backend, and every executor is
-    bit-identical — it is a performance knob, not a semantics knob.  It
-    joins the sweep group key, so mixing executors in one sweep simply
+    ``"auto"`` (the default) is ``blocked`` on every backend, and every
+    executor is bit-identical — it is a performance knob, not a semantics
+    knob.  It joins the sweep group key, so mixing executors in one sweep simply
     splits groups.
 
     ``eq=False``: scenarios may carry an ndarray ``bw_schedule``, so equality
@@ -81,8 +82,8 @@ class Scenario:
         if self.total_s < self.dt:
             raise ValueError(f"total_s ({self.total_s}) must cover at least "
                              f"one tick of dt ({self.dt})")
-        # Validate the executor name eagerly (resolution happens at run
-        # time, so "auto" stays backend-relative).
+        # Validate the executor name eagerly (resolution happens again at
+        # run time, against the backend in use then).
         engine.resolve_executor(self.executor)
 
 
@@ -247,11 +248,8 @@ def _run_group(key: _GroupKey, stacked, batch: int, devices):
     Returns (sim, metrics) pytrees with numpy leaves and a leading batch
     axis of exactly ``batch`` (device padding stripped).
     """
-    # Shard only when every device gets at least one real lane: smaller
-    # groups would pay padding lanes plus an extra compiled executable for
-    # no wall-clock win over the plain vmapped runner.
-    if devices is not None and len(devices) > 1 and batch >= len(devices):
-        from repro.distributed import sharding as shd
+    from repro.distributed import sharding as shd
+    if shd.should_shard(batch, devices):
         stacked, _ = shd.pad_batch(stacked, len(devices))
         mesh = shd.batch_mesh(devices)
         runner = engine.get_sharded_runner(
@@ -278,11 +276,11 @@ def sweep(scenarios: Sequence[Scenario], *,
     runner (which shares the per-group cache with :func:`run`).
 
     ``devices`` selects the devices groups shard across (default: all local
-    devices).  With more than one device, each group batch is padded to a
-    multiple of the device count and dispatched through a ``shard_map``
-    runner with donated input buffers; on a single device — or with an
-    explicitly empty ``devices`` sequence — the plain vmapped runner is
-    used and results are identical.
+    devices).  With more than one device, each group with at least one lane
+    per device is padded to a multiple of the device count and dispatched
+    through a ``shard_map`` runner with donated input buffers; on a single
+    device — or with an explicitly empty ``devices`` sequence — the plain
+    vmapped runner is used and results are identical.
     """
     if devices is None:
         devices = jax.devices()

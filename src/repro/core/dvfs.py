@@ -55,7 +55,7 @@ import jax.numpy as jnp
 
 from . import network_model
 from .tickstate import const_table
-from .types import CpuProfile, SimState, freq_table
+from .types import CpuProfile, SimState, freq_table, partition_sum
 
 #: Lumos-style technology presets: a high-performance process ("hp" —
 #: steep leakage, shallow V(f) slope, clocks high) and a low-power process
@@ -258,14 +258,14 @@ class DvfsNetworkModel:
         # the row slices instead of SimState fields.
         active = (remaining > 0.0).astype(jnp.float32)          # [P]
         cc = jnp.maximum(params.cc, 0.0) * active
-        total_ch = jnp.sum(cc)
+        total_ch = partition_sum(cc)
 
-        n_active = jnp.maximum(jnp.sum(active), 1.0)
-        avg_win = jnp.sum(window * active) / n_active
+        n_active = jnp.maximum(partition_sum(active), 1.0)
+        avg_win = partition_sum(window * active) / n_active
         r1 = network_model.channel_rate(net, window, avg_file_mb,
                                         params.pp, params.par)
         demand = cc * r1                                        # [P]
-        total_demand = jnp.sum(demand)
+        total_demand = partition_sum(demand)
 
         b_avail = net.bandwidth_mbps * (1.0 - net.cross_traffic) * bw_scale
         eff = network_model.contention_efficiency(net, total_ch, avg_win)
@@ -292,7 +292,8 @@ class DvfsNetworkModel:
             new_window,
             jnp.stack([sim_row[..., lay.off_t] + dt,
                        sim_row[..., lay.off_energy] + pw * dt,
-                       sim_row[..., lay.off_bytes] + jnp.sum(moved)]),
+                       sim_row[..., lay.off_bytes]
+                       + partition_sum(moved)]),
         ])
         out = network_model.NetOut(tput_mbps=tput, part_rate=part_rate,
                                    cpu_load=load, power_w=pw,

@@ -55,17 +55,18 @@ An **executor** decides how those semantics are driven:
   is a handful of ``np.stack`` calls instead of per-lane pytree traffic.
 * ``pallas`` — a fused network-step + energy-model + controller-FSM tick
   kernel (one ``pallas_call`` per transfer, per-tick metrics stored from
-  inside the kernel), built on ``repro.kernels.pallas_compat``.  Runs
-  compiled on TPU; everywhere else it runs in interpret mode so tier-1
-  stays green on CPU.  ``observe=True`` is not supported here — use
-  ``blocked``.
+  inside the kernel).  It runs in Pallas interpret mode only: the TPU
+  compiler refuses it (the packed parameter row is not a lane-aligned
+  block under ``vmap``, and the unbatched kernel uses ``dynamic_slice``),
+  so an explicit ``pallas`` request on a TPU backend raises at resolution
+  (ROADMAP A2).  ``observe=True`` is not supported here either.
 
-``executor="auto"`` resolves per backend (:func:`resolve_executor`):
-``pallas`` on TPU, ``blocked`` otherwise, and always ``blocked`` when the
-observation hook is on.  Because the pack/unpack adapters are pure
+``executor="auto"`` resolves to ``blocked`` on every backend
+(:func:`resolve_executor`).  Because the pack/unpack adapters are pure
 concatenation/slicing, every executor is bit-identical on the golden
-run/sweep/fleet cells (tests/test_executors.py); the choice is purely a
-performance/deployment knob.
+run/sweep/fleet cells (tests/test_executors.py).  The wave and sharded
+runners speak ``reference`` and ``blocked`` only; an explicit ``pallas``
+request there raises instead of being swapped for another executor.
 
 Everything numeric (testbed profile, SLA hyper-parameters, dataset sizes,
 initial operating point, bandwidth schedule) arrives as traced ``ScanInputs``
@@ -89,7 +90,7 @@ import numpy as np
 from . import tickstate
 from . import tuners
 from .types import (CpuProfile, NetParams, SLAParams, TickMetrics,
-                    TransferParams, TunerState)
+                    TransferParams, TunerState, partition_sum)
 
 # Chunking of the early-exit loop.  Purely a performance knob (completion
 # masking keeps any chunking bit-identical): larger chunks amortize the
@@ -102,7 +103,7 @@ MIN_CHUNK = 512
 MAX_CHUNKS = 64
 
 #: Executor names accepted everywhere an ``executor=`` knob exists
-#: ("auto" additionally resolves per backend).
+#: (plus "auto", which is "blocked").
 EXECUTORS = ("reference", "blocked", "pallas")
 
 
@@ -110,24 +111,27 @@ def resolve_executor(executor: str = "auto", *, observe: bool = False,
                      backend: Optional[str] = None) -> str:
     """Resolve an executor request to a concrete executor name.
 
-    ``auto`` picks ``pallas`` on TPU and ``blocked`` everywhere else
-    (interpret-mode pallas is a correctness path, not a fast path), and
-    always ``blocked`` when the observation hook is on (the fused kernel
-    does not emit Observation traces).  Explicit names pass through after
-    validation; ``pallas`` with ``observe=True`` is rejected here, at the
-    resolution boundary, instead of deep inside a trace.
+    ``auto`` is ``blocked`` on every backend.  Explicit names pass through
+    after validation, except where they cannot run, which is rejected here
+    at the resolution boundary instead of deep inside a trace or compile:
+    ``pallas`` with ``observe=True`` (the fused kernel emits no Observation
+    traces), and ``pallas`` on a ``tpu`` backend (the TPU compiler refuses
+    the kernel; see ROADMAP A2).
     """
     if executor == "auto":
-        if observe:
-            return "blocked"
-        backend = backend or jax.default_backend()
-        return "pallas" if backend == "tpu" else "blocked"
+        return "blocked"
     if executor not in EXECUTORS:
         raise ValueError(f"unknown executor {executor!r}; expected one of "
                          f"{('auto',) + EXECUTORS}")
-    if executor == "pallas" and observe:
-        raise ValueError("the pallas executor does not support observe=True;"
-                         " use executor='blocked' (or 'auto')")
+    if executor == "pallas":
+        if observe:
+            raise ValueError("the pallas executor does not support "
+                             "observe=True; use executor='blocked' (or "
+                             "'auto')")
+        if (backend or jax.default_backend()) == "tpu":
+            raise ValueError(
+                "the fused pallas tick kernel does not compile for TPU yet "
+                "(ROADMAP A2); use executor='blocked' (or 'auto')")
     return executor
 
 
@@ -233,7 +237,7 @@ def _controller_tick(controller, ts: TunerState, sim, load, net, cpu,
         avg_tput=ts.acc_mb / jnp.maximum(ts.acc_s, 1e-6),
         energy_j=ts.acc_j,
         avg_power=ts.acc_j / jnp.maximum(ts.acc_s, 1e-6),
-        remaining_mb=jnp.sum(sim.remaining_mb),
+        remaining_mb=partition_sum(sim.remaining_mb),
         cpu_load=load,
         interval_s=ts.acc_s,
     )
@@ -279,7 +283,7 @@ def make_step_fn(controller, env, cpu: CpuProfile, inp: ScanInputs, *,
         sim, ts = carry
         step_idx, bw_scale = xs
 
-        done = jnp.sum(sim.remaining_mb) <= 0.0
+        done = partition_sum(sim.remaining_mb) <= 0.0
         if n_steps is not None:
             done = jnp.logical_or(done, step_idx >= n_steps)
         live = jnp.logical_not(done)
@@ -321,7 +325,7 @@ def make_step_fn(controller, env, cpu: CpuProfile, inp: ScanInputs, *,
             cores=jnp.where(live, ts.cores, zi),
             freq_ghz=f * live,
             # Recorded POST-step: True from the tick the transfer drained.
-            done=jnp.sum(sim2.remaining_mb) <= 0.0,
+            done=partition_sum(sim2.remaining_mb) <= 0.0,
         )
         if not observe:
             return (sim2, ts), metrics
@@ -331,7 +335,7 @@ def make_step_fn(controller, env, cpu: CpuProfile, inp: ScanInputs, *,
             avg_tput=(ts_pre.acc_mb / win_s) * live,
             avg_power=(ts_pre.acc_j / win_s) * live,
             cpu_load=out.cpu_load * live,
-            remaining_mb=jnp.sum(sim2.remaining_mb) * live,
+            remaining_mb=partition_sum(sim2.remaining_mb) * live,
             num_ch=ts_pre.num_ch * live,
             cores=jnp.where(live, ts_pre.cores, zi),
             freq_idx=jnp.where(live, ts_pre.freq_idx, zi),
@@ -461,7 +465,7 @@ def build_core(controller, env, cpu: CpuProfile, *, n_steps: int, dt: float,
                 k, f32, _, _ = carry
                 return jnp.logical_and(
                     k < n_chunks,
-                    jnp.sum(f32[..., :n_partitions]) > 0.0)
+                    partition_sum(f32[..., :n_partitions]) > 0.0)
 
             def body(carry):
                 k, f32, i32, buf = carry
@@ -481,7 +485,7 @@ def build_core(controller, env, cpu: CpuProfile, *, n_steps: int, dt: float,
             def cond(carry):
                 k, (sim, _), _ = carry
                 return jnp.logical_and(k < n_chunks,
-                                       jnp.sum(sim.remaining_mb) > 0.0)
+                                       partition_sum(sim.remaining_mb) > 0.0)
 
             def body(carry):
                 k, state, buf = carry
@@ -513,12 +517,11 @@ def _build_pallas_core(controller, env, cpu: CpuProfile, *, n_steps: int,
     ``step_arrays`` form — inside an early-exiting while loop, and stores
     per-tick metrics straight into the output buffers (pre-filled with the
     never-executed-tick values, so the trace is bit-identical to the
-    reference scan).  Compiled on TPU via ``kernels/pallas_compat``;
-    interpret mode elsewhere.
+    reference scan).  Always runs in interpret mode: the TPU compiler
+    refuses this kernel (ROADMAP A2), and :func:`resolve_executor` keeps it
+    off TPU backends.
     """
     from jax.experimental import pallas as pl
-
-    interpret = jax.default_backend() != "tpu"
 
     def core(inp: ScanInputs):
         n_partitions = int(np.shape(inp.pp)[-1])
@@ -575,7 +578,7 @@ def _build_pallas_core(controller, env, cpu: CpuProfile, *, n_steps: int,
             def cond(c):
                 i, (sim, _) = c
                 return jnp.logical_and(i < n_steps,
-                                       jnp.sum(sim.remaining_mb) > 0.0)
+                                       partition_sum(sim.remaining_mb) > 0.0)
 
             def body(c):
                 i, carry = c
@@ -603,14 +606,9 @@ def _build_pallas_core(controller, env, cpu: CpuProfile, *, n_steps: int,
             jax.ShapeDtypeStruct((n_steps,), jnp.float32),  # freq_ghz
             jax.ShapeDtypeStruct((n_steps,), jnp.int32),   # done
         ]
-        kwargs = {}
-        if interpret:
-            kwargs["interpret"] = True
-        else:
-            from repro.kernels import pallas_compat
-            kwargs["compiler_params"] = pallas_compat.CompilerParams()
         f32, i32, tput, power, load, nch, cores, freq, done = pl.pallas_call(
-            kernel, out_shape=out_shape, **kwargs)(prow, bw, f0, i0, *consts)
+            kernel, out_shape=out_shape, interpret=True)(
+                prow, bw, f0, i0, *consts)
         sim, ts = lay.unpack_state(f32, i32)
         metrics = TickMetrics(tput_mbps=tput, power_w=power, cpu_load=load,
                               num_ch=nch, cores=cores, freq_ghz=freq,
@@ -671,7 +669,7 @@ def get_runner(controller_code, env_code, cpu: CpuProfile, n_steps: int,
     shape-compatible, so lanes tend to finish at similar times).
 
     ``executor`` is resolved first (:func:`resolve_executor`), so
-    ``"auto"`` and its backend-resolved name share one cache entry.
+    ``"auto"`` and ``"blocked"`` share one cache entry.
     """
     executor = resolve_executor(executor, observe=observe)
     key = (controller_code, env_code, cpu, n_steps, dt, ctrl_every,
@@ -791,14 +789,18 @@ def build_blocked_wave_core(controller, env, cpu: CpuProfile, *,
     return core
 
 
-def _resolve_wave_executor(executor: str, n_partitions) -> str:
-    """Wave runners support ``reference`` and ``blocked``; a ``pallas``
-    resolution falls back to ``blocked`` (bit-identical), which is the
-    executor the wave batching was shaped for."""
+def _resolve_unfused_executor(executor: str, *, wave: bool = False,
+                              n_partitions: Optional[int] = None) -> str:
+    """The wave and sharded runners speak ``reference`` and ``blocked``: an
+    explicit ``pallas`` request raises (the fused kernel has neither form)
+    instead of being swapped for another executor.  A ``blocked`` wave
+    runner also needs the static ``n_partitions``."""
     executor = resolve_executor(executor)
     if executor == "pallas":
-        executor = "blocked"
-    if executor == "blocked" and n_partitions is None:
+        raise ValueError("wave and sharded runners support "
+                         "executor='reference' or 'blocked' (or 'auto'), "
+                         "not 'pallas'")
+    if wave and executor == "blocked" and n_partitions is None:
         raise ValueError("blocked wave runners need n_partitions (the "
                          "static TickLayout width)")
     return executor
@@ -830,7 +832,8 @@ def get_wave_runner(controller_code, env_code, cpu: CpuProfile,
     ``done_at`` is relative to the *lane's* tick clock, not the fleet's, so
     a recycled slot is indistinguishable from a new lane.
     """
-    executor = _resolve_wave_executor(executor, n_partitions)
+    executor = _resolve_unfused_executor(executor, wave=True,
+                                         n_partitions=n_partitions)
     key = (controller_code, env_code, cpu, wave_steps, dt, ctrl_every,
            executor, n_partitions, donate)
 
@@ -869,7 +872,8 @@ def get_sharded_wave_runner(controller_code, env_code, cpu: CpuProfile,
 
     from repro.distributed import sharding as shd
 
-    executor = _resolve_wave_executor(executor, n_partitions)
+    executor = _resolve_unfused_executor(executor, wave=True,
+                                         n_partitions=n_partitions)
     key = (controller_code, env_code, cpu, wave_steps, dt, ctrl_every,
            devices, executor, n_partitions)
 
@@ -879,14 +883,14 @@ def get_sharded_wave_runner(controller_code, env_code, cpu: CpuProfile,
             core = build_blocked_wave_core(
                 controller_code, env_code, cpu, wave_steps=wave_steps,
                 dt=dt, ctrl_every=ctrl_every, n_partitions=n_partitions)
-            f = shd.shard_map(jax.vmap(core), mesh=mesh,
+            f = jax.shard_map(jax.vmap(core), mesh=mesh,
                               in_specs=(P("batch"),) * 5,
                               out_specs=P("batch"), check_vma=False)
             return jax.jit(f, donate_argnums=(2, 3))
         core = build_wave_core(controller_code, env_code, cpu,
                                wave_steps=wave_steps, dt=dt,
                                ctrl_every=ctrl_every)
-        f = shd.shard_map(jax.vmap(core), mesh=mesh,
+        f = jax.shard_map(jax.vmap(core), mesh=mesh,
                           in_specs=(P("batch"),) * 4,
                           out_specs=P("batch"), check_vma=False)
         return jax.jit(f, donate_argnums=(1, 2))
@@ -906,17 +910,14 @@ def get_sharded_runner(controller_code, env_code, cpu: CpuProfile,
     lanes all finish early stops scanning without waiting for the others.
     Input batches must be padded to a multiple of ``len(devices)``
     (``repro.distributed.sharding.pad_batch``) and placed with
-    ``shard_batch``; the jit donates the input buffers.  A ``pallas``
-    resolution falls back to ``blocked`` here (bit-identical) — the fused
-    kernel composes with ``vmap`` but not yet with ``shard_map``.
+    ``shard_batch``; the jit donates the input buffers.  Supports
+    ``reference`` and ``blocked``; an explicit ``pallas`` request raises.
     """
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed import sharding as shd
 
-    executor = resolve_executor(executor)
-    if executor == "pallas":
-        executor = "blocked"
+    executor = _resolve_unfused_executor(executor)
     key = (controller_code, env_code, cpu, n_steps, dt, ctrl_every,
            devices, early_exit, chunk, executor)
 
@@ -926,7 +927,7 @@ def get_sharded_runner(controller_code, env_code, cpu: CpuProfile,
                           dt=dt, ctrl_every=ctrl_every,
                           early_exit=early_exit, chunk=chunk,
                           executor=executor)
-        f = shd.shard_map(jax.vmap(core), mesh=mesh, in_specs=(P("batch"),),
+        f = jax.shard_map(jax.vmap(core), mesh=mesh, in_specs=(P("batch"),),
                           out_specs=P("batch"), check_vma=False)
         return jax.jit(f, donate_argnums=0)
 

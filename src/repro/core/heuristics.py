@@ -15,7 +15,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from .types import (CpuProfile, DatasetSpec, NetworkProfile, SLA, SLAPolicy,
-                    TransferParams)
+                    TransferParams, partition_sum)
 
 
 def split_large_files(spec: DatasetSpec, bdp_mb: float) -> tuple[DatasetSpec, float]:
@@ -97,7 +97,7 @@ def redistribute_channels(num_ch, remaining_mb, part_rate=None):
     Jit-safe (used inside the engine scan).
     """
     remaining = jnp.maximum(remaining_mb, 0.0)
-    w = remaining / jnp.maximum(jnp.sum(remaining), 1e-6)
+    w = remaining / jnp.maximum(partition_sum(remaining), 1e-6)
     # Fluid (continuous) channel allocation: a cc of 0.5 models a channel
     # duty-cycled at 50% — the continuous-time limit of the paper's integer
     # rounding, and what keeps ΣccLevel_i == numCh exactly.

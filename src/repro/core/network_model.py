@@ -23,7 +23,8 @@ from typing import NamedTuple
 import jax.numpy as jnp
 
 from . import energy_model
-from .types import CpuProfile, NetworkProfile, SimState, TransferParams
+from .types import (CpuProfile, NetworkProfile, SimState, TransferParams,
+                    partition_sum)
 
 
 class NetOut(NamedTuple):
@@ -76,16 +77,16 @@ def step(
         energy = energy_model
     active = (state.remaining_mb > 0.0).astype(jnp.float32)     # [P]
     cc = jnp.maximum(params.cc, 0.0) * active
-    total_ch = jnp.sum(cc)
+    total_ch = partition_sum(cc)
 
     # Contention sees only the partitions that still hold channels: drained
     # partitions' windows keep ramping toward the profile window and would
     # otherwise skew the saturation estimate late in the transfer.
-    n_active = jnp.maximum(jnp.sum(active), 1.0)
-    avg_win = jnp.sum(state.window_mb * active) / n_active
+    n_active = jnp.maximum(partition_sum(active), 1.0)
+    avg_win = partition_sum(state.window_mb * active) / n_active
     r1 = channel_rate(profile, state.window_mb, avg_file_mb, params.pp, params.par)
     demand = cc * r1                                            # [P]
-    total_demand = jnp.sum(demand)
+    total_demand = partition_sum(demand)
 
     b_avail = profile.bandwidth_mbps * (1.0 - profile.cross_traffic) * bw_scale
     eff = contention_efficiency(profile, total_ch, avg_win)
@@ -115,7 +116,7 @@ def step(
         window_mb=window,
         t=state.t + dt,
         energy_j=state.energy_j + pw * dt,
-        bytes_moved=state.bytes_moved + jnp.sum(moved),
+        bytes_moved=state.bytes_moved + partition_sum(moved),
     )
     out = NetOut(tput_mbps=tput, part_rate=part_rate, cpu_load=load,
                  power_w=pw, num_ch=total_ch)

@@ -18,6 +18,7 @@ import dataclasses
 import enum
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -178,6 +179,36 @@ class TransferParams(NamedTuple):
     cc: jnp.ndarray        # [P] concurrent channels per partition
     cores: jnp.ndarray     # [] active core count (int32)
     freq_idx: jnp.ndarray  # [] index into freq_levels_ghz (int32)
+
+
+def _fold_partitions(x):
+    """Left fold over the trailing axis, as a chain of elementwise adds."""
+    acc = x[..., 0]
+    for i in range(1, x.shape[-1]):
+        acc = acc + x[..., i]
+    return acc
+
+
+def _reduce_partitions(x):
+    return jnp.sum(x, axis=-1)
+
+
+def partition_sum(x):
+    """Sum over the trailing partition axis, in index order on every backend
+    and at every batch width.
+
+    Every per-tick sum over partitions goes through here, so that a lane's
+    f32 bits do not depend on how many lanes share its launch.  XLA:TPU
+    lowers a ``jnp.sum`` over this axis in a layout-dependent order, and a
+    one-lane batch gets another layout than a wider one, so there the sum is
+    an explicit chain of adds, whose order is fixed by the static partition
+    count.  XLA:CPU's reduce already adds in index order, and contracts
+    each product feeding it into an FMA the same way whatever the fusion;
+    an explicit chain of adds there would be contracted by LLVM differently
+    from one fusion to the next, so the CPU keeps the reduce.
+    """
+    return jax.lax.platform_dependent(x, cpu=_reduce_partitions,
+                                      default=_fold_partitions)
 
 
 class SimState(NamedTuple):

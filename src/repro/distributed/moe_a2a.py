@@ -25,13 +25,11 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.sharding import get_abstract_mesh, shard_map
-
 from repro.models.common import ModelConfig
 
 
 def _axes():
-    m = get_abstract_mesh()
+    m = jax.sharding.get_abstract_mesh()
     names = m.axis_names
     dp = tuple(a for a in ("pod", "data") if a in names)
     return m, dp, ("model" if "model" in names else None)
@@ -111,7 +109,7 @@ def moe_a2a(cfg: ModelConfig, p, x, *, capacity_factor: float = 1.25):
 
     specs_in = (x_spec, P(None, None), P(model_ax, None, None),
                 P(model_ax, None, None), P(model_ax, None, None))
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body, mesh=m, in_specs=specs_in,
         out_specs=(x_spec, P()), check_vma=False,
     )(x, p["router"], p["wg"], p["wu"], p["wd"])

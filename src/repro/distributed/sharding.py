@@ -93,33 +93,6 @@ class MeshConfig:
         return batch_mesh(self.devices())
 
 
-def set_mesh(mesh: Mesh):
-    """Version-compatible ambient-mesh context manager.
-
-    jax >= 0.5 exposes ``jax.set_mesh``; on older versions (0.4.x) the
-    ``Mesh`` object itself is the context manager that installs the ambient
-    mesh for ``with_sharding_constraint`` / ``shard_map``.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, **kwargs):
-    """Version-compatible ``jax.shard_map``.
-
-    On jax 0.4.x the implementation lives in ``jax.experimental.shard_map``
-    and the replication-check kwarg is ``check_rep`` (not ``check_vma``).
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kwargs)
-    from jax.experimental.shard_map import shard_map as _sm
-    if "check_vma" in kwargs:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
-
-
 def batch_mesh(devices=None) -> Mesh:
     """1-D mesh with a single ``batch`` axis over ``devices``.
 
@@ -129,6 +102,20 @@ def batch_mesh(devices=None) -> Mesh:
     """
     devices = tuple(jax.devices() if devices is None else devices)
     return Mesh(np.asarray(devices), ("batch",))
+
+
+def should_shard(lanes: int, devices) -> bool:
+    """Whether a batch of ``lanes`` engine lanes runs sharded over
+    ``devices`` (``repro.api.sweep`` groups and ``repro.fleet.run_fleet``
+    waves alike).
+
+    Only when every device gets at least one lane: a smaller batch would
+    pay padding lanes plus an extra compiled executable for no wall-clock
+    win over the plain vmapped runner on one device.  A lane's results do
+    not depend on the path, since every per-tick partition sum has a fixed
+    order at any batch width (``repro.core.types.partition_sum``).
+    """
+    return devices is not None and 1 < len(devices) <= lanes
 
 
 def pad_batch(tree, multiple: int, *, fill: str = "repeat"):
@@ -170,18 +157,6 @@ def shard_batch(tree, mesh: Mesh):
     :func:`pad_batch` first."""
     return jax.device_put(tree, NamedSharding(mesh, P("batch")))
 
-
-def get_abstract_mesh():
-    """Version-compatible ``jax.sharding.get_abstract_mesh``.
-
-    Falls back to the thread-resource physical mesh on jax 0.4.x, which
-    supports the same ``.empty`` / ``.shape`` / ``.axis_names`` queries the
-    callers use.
-    """
-    if hasattr(jax.sharding, "get_abstract_mesh"):
-        return jax.sharding.get_abstract_mesh()
-    from jax.interpreters import pxla
-    return pxla.thread_resources.env.physical_mesh
 
 # (regex on '/'-joined path, spec WITHOUT the stacked-layer axis)
 _RULES = (

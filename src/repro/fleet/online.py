@@ -202,8 +202,6 @@ def run_fleet_online(stream: Iterable[TransferRequest],
     if wave_steps < 1:
         raise ValueError(f"wave_s={cfg.wave_s} shorter than dt={cfg.dt}")
     executor = engine.resolve_executor(cfg.executor)
-    if executor == "pallas":
-        executor = "blocked"
     if executor != "blocked":
         raise ValueError(
             f"the online loop speaks the flat blocked wave contract; "

@@ -124,10 +124,9 @@ def _run_wave_group(key, lanes: list, shares: list, wave_steps: int,
     # shapes per group to O(log max_concurrency); the filler lanes are
     # zeroed, i.e. born drained, and cost nothing.
     bucket = 1 << max(n - 1, 0).bit_length()
-    ndev = len(devices) if devices is not None else 1
     n_parts = lay.n_partitions if executor == "blocked" else None
-    if ndev > 1 and n >= ndev:
-        bucket = -(-bucket // ndev) * ndev
+    if shd.should_shard(n, devices):
+        bucket = -(-bucket // len(devices)) * len(devices)
         batch, _ = shd.pad_batch(batch, bucket, fill="zero")
         mesh = shd.batch_mesh(devices)
         runner = engine.get_sharded_wave_runner(
@@ -178,9 +177,10 @@ def run_fleet(trace: Sequence[TransferRequest], hosts: Sequence[Host], *,
     the fleet runs until every transfer completes or exhausts its budget.
     ``devices`` selects accelerator devices for lane sharding (default: all
     local devices; single-device hosts use the plain vmapped runner).
-    ``executor`` picks the engine lowering for the wave runners (every
-    executor is bit-identical; a ``pallas`` resolution falls back to
-    ``blocked``, the executor the wave batching is shaped for).
+    ``executor`` picks the engine lowering for the wave runners:
+    ``reference`` or ``blocked`` (bit-identical; ``auto`` is ``blocked``,
+    the executor the wave batching is shaped for).  ``pallas`` raises —
+    the fused kernel has no wave form.
 
     ``faults`` injects a :class:`repro.workloads.faults.FaultSchedule`
     (or any object with its five driver methods): host-loss windows kill
@@ -201,8 +201,6 @@ def run_fleet(trace: Sequence[TransferRequest], hosts: Sequence[Host], *,
     if devices is None:
         devices = jax.devices()
     executor = engine.resolve_executor(executor)
-    if executor == "pallas":
-        executor = "blocked"
 
     reqs = sorted(trace, key=request_sort_key)
 

@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 NEG_INF = -1.0e30
 
 
@@ -156,7 +154,7 @@ def flash_attention_bwd_bhtd(q, k, v, o, lse, do, *, causal=True, window=0,
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, Tq, hd), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, hd), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -179,7 +177,7 @@ def flash_attention_bwd_bhtd(q, k, v, o, lse, do, *, causal=True, window=0,
                    jax.ShapeDtypeStruct((B, H, Tk, hd), q.dtype)),
         scratch_shapes=[pltpu.VMEM((bk, hd), jnp.float32),
                         pltpu.VMEM((bk, hd), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -19,8 +19,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 
 def _rglru_kernel(a_ref, b_ref, h_ref, carry_scr, *, bt: int):
     it = pl.program_id(2)
@@ -68,7 +66,7 @@ def rglru_scan(a, b, *, bt: int = 256, bc: int = 512,
         out_specs=pl.BlockSpec((1, bt, bc), lambda bb, ic, it: (bb, it, ic)),
         out_shape=jax.ShapeDtypeStruct((B, T, C), a.dtype),
         scratch_shapes=[pltpu.VMEM((1, bc), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)

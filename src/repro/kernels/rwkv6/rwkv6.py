@@ -20,8 +20,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, s_scr, *,
                 bt: int):
@@ -78,7 +76,7 @@ def wkv_bhtd(r, k, v, w, u, *, bt: int = 128, interpret: bool = False):
         out_specs=pl.BlockSpec((1, 1, bt, hd), lambda b, h, it: (b, h, it, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, T, hd), r.dtype),
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(r, k, v, w, u)

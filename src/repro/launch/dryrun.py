@@ -31,8 +31,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import ARCHS, SHAPES, cells, get_config
-from repro.distributed.sharding import (param_specs, set_mesh, shardings,
-                                        zero_specs)
+from repro.distributed.sharding import param_specs, shardings, zero_specs
 from repro.launch.hlo_stats import collective_bytes, roofline_terms
 from repro.launch.input_specs import input_specs
 from repro.launch.mesh import make_production_mesh
@@ -130,7 +129,7 @@ def build_cell(cfg, shape_name: str, mesh, moe_impl=None, microbatches=None,
 
 def _compile(cfg, shape_name, mesh, moe_impl, microbatches=None,
              dp_only=False):
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted, args = build_cell(cfg, shape_name, mesh, moe_impl=moe_impl,
                                   microbatches=microbatches, dp_only=dp_only)
         lowered = jitted.lower(*args)

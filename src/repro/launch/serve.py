@@ -13,7 +13,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config, get_smoke_config
-from repro.distributed.sharding import set_mesh
 from repro.launch.mesh import make_host_mesh
 from repro.models import build
 from repro.serve import make_decode_step, make_prefill
@@ -33,7 +32,7 @@ def main():
     bundle = build(cfg)
     mesh = make_host_mesh(model=args.tp)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = bundle.init_params(jax.random.PRNGKey(0))
         B, T, N = args.batch, args.prompt_len, args.new_tokens
         prompt = jax.random.randint(jax.random.PRNGKey(1), (B, T), 0,

@@ -19,7 +19,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_config, get_smoke_config
 from repro.core.types import SLA, SLAPolicy
 from repro.data import SyntheticSource, batches
-from repro.distributed.sharding import param_specs, set_mesh, shardings
+from repro.distributed.sharding import param_specs, shardings
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models import build
 from repro.optim import AdamWConfig, OptState
@@ -57,7 +57,7 @@ def main():
     print(f"mesh: {dict(mesh.shape)}  arch: {cfg.name} "
           f"({cfg.param_count() / 1e6:.1f}M params)")
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = init_train_state(bundle, jax.random.PRNGKey(0))
         pspecs = param_specs(state.params,
                              model_divisor=mesh.shape.get("model", 1))

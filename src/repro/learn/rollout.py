@@ -61,14 +61,13 @@ def run_observed(scenarios: Sequence) -> list[ObservedRun]:
 
     results: list = [None] * len(prepared)
     for key, idxs in groups.items():
-        # The fused pallas kernel has no observation outputs; fall back to
-        # the bit-identical blocked executor for observed runs (an explicit
-        # Scenario.executor="reference" is still honored).
-        ex = "blocked" if key.executor == "pallas" else key.executor
+        # An explicit Scenario.executor="pallas" raises in get_runner: the
+        # fused kernel has no observation outputs.
         if len(idxs) == 1:
             runner = engine.get_runner(
                 key.ctrl_code, key.env_code, key.cpu, key.n_steps, key.dt,
-                key.ctrl_every, batched=False, observe=True, executor=ex)
+                key.ctrl_every, batched=False, observe=True,
+                executor=key.executor)
             out = runner(prepared[idxs[0]].inputs)
             batch = [(idxs[0], out)]
         else:
@@ -76,7 +75,8 @@ def run_observed(scenarios: Sequence) -> list[ObservedRun]:
                                    *[prepared[i].inputs for i in idxs])
             runner = engine.get_runner(
                 key.ctrl_code, key.env_code, key.cpu, key.n_steps, key.dt,
-                key.ctrl_every, batched=True, observe=True, executor=ex)
+                key.ctrl_every, batched=True, observe=True,
+                executor=key.executor)
             sim, ts, metrics, obs = runner(stacked)
             batch = [(i, jax.tree.map(lambda x, b=b: x[b],
                                       (sim, ts, metrics, obs)))

@@ -18,8 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.distributed.sharding import get_abstract_mesh
-
 from .common import ModelConfig
 
 NEG_INF = -2.0e38
@@ -32,7 +30,7 @@ def _dtype(cfg: ModelConfig):
 def _model_axis_size():
     """Size of the 'model' mesh axis in the current mesh context (or None)."""
     try:
-        m = get_abstract_mesh()
+        m = jax.sharding.get_abstract_mesh()
         if m.empty:
             return None
         return dict(m.shape).get("model")
@@ -41,7 +39,7 @@ def _model_axis_size():
 
 
 def _dp_axes():
-    m = get_abstract_mesh()
+    m = jax.sharding.get_abstract_mesh()
     return tuple(a for a in ("pod", "data") if a in m.axis_names)
 
 
@@ -50,7 +48,7 @@ def logits_shard(x):
     device).  Without it GSPMD replicated fp32 logits for the CE chunks
     (measured: 16 copies of 2.1 GB on yi-9b train)."""
     from jax.sharding import PartitionSpec as P
-    m = get_abstract_mesh()
+    m = jax.sharding.get_abstract_mesh()
     if m.empty:
         return x
     msize = dict(m.shape).get("model")
@@ -80,7 +78,7 @@ def residual_shard(x):
     attention head boundary and the reduce-scatter after row-parallel
     matmuls, exactly as in hand-written Megatron SP."""
     from jax.sharding import PartitionSpec as P
-    m = get_abstract_mesh()
+    m = jax.sharding.get_abstract_mesh()
     if m.empty:
         return x
     msize = dict(m.shape).get("model")

@@ -7,7 +7,6 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=4")
 import jax  # noqa: E402
 import pytest  # noqa: E402
 
-from repro.distributed.sharding import set_mesh
 from repro.configs import SHAPES, get_smoke_config  # noqa: E402
 from repro.launch.dryrun import build_cell  # noqa: E402
 from repro.launch.hlo_stats import collective_bytes, roofline_terms  # noqa: E402
@@ -30,7 +29,7 @@ def mesh():
 ])
 def test_cell_lowers_on_small_mesh(mesh, arch, shape):
     cfg = get_smoke_config(arch)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted, args = build_cell(cfg, shape, mesh, microbatches=2)
         lowered = jitted.lower(*args)       # lowering exercises GSPMD specs
     assert "HloModule" in lowered.as_text()[:200] or lowered is not None

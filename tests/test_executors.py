@@ -1,10 +1,12 @@
 """Executor parity: the flat-state lowering must be invisible.
 
-Covers the PR 7 lowering contract (``repro.core.tickstate`` +
+Covers the lowering contract (``repro.core.tickstate`` +
 ``repro.core.engine`` executors): pack/unpack round-trips are bit-exact,
 and the ``blocked`` and ``pallas`` (interpret-mode) executors reproduce the
-``reference`` executor — and therefore the PR 5 RUN_GOLDEN values — bit
-for bit across run, sweep, fleet, and observed-rollout cells.
+``reference`` executor — and therefore the RUN_GOLDEN values — bit for bit
+across run, sweep, fleet, and observed-rollout cells.  ``auto`` is
+``blocked`` on every backend, and paths the fused kernel cannot run refuse
+an explicit ``pallas`` request.
 """
 import os
 import subprocess
@@ -142,9 +144,13 @@ def test_layout_validates_and_hashes():
 def test_resolve_executor():
     assert engine.resolve_executor("reference") == "reference"
     assert engine.resolve_executor("auto", backend="cpu") == "blocked"
-    assert engine.resolve_executor("auto", backend="tpu") == "pallas"
+    assert engine.resolve_executor("auto", backend="tpu") == "blocked"
     assert engine.resolve_executor("auto", backend="tpu",
                                    observe=True) == "blocked"
+    # the fused kernel runs interpreted off-TPU and is refused on TPU
+    assert engine.resolve_executor("pallas", backend="cpu") == "pallas"
+    with pytest.raises(ValueError, match="ROADMAP A2"):
+        engine.resolve_executor("pallas", backend="tpu")
     with pytest.raises(ValueError, match="unknown executor"):
         engine.resolve_executor("vectorized")
     with pytest.raises(ValueError, match="observe"):
@@ -277,14 +283,43 @@ def test_observed_rollout_bit_identity_across_executors():
     assert _leaves_equal(a.obs, b.obs)
 
 
-def test_observed_pallas_scenario_falls_back_to_blocked():
-    """A pallas scenario still works through run_observed (blocked
-    fallback), bit-identical to the reference trace."""
+def test_observed_refuses_pallas_and_auto_matches_reference():
+    """No fallback: an explicit pallas scenario raises in run_observed (the
+    fused kernel emits no Observation traces) instead of being swapped for
+    blocked; the auto scenario runs observed, equal to the reference."""
+    with pytest.raises(ValueError, match="observe"):
+        learn.run_observed([_scn(CHAMELEON, "me", FAST, executor="pallas")])
     (ref,) = learn.run_observed(
         [_scn(CHAMELEON, "me", FAST, executor="reference")])
-    (got,) = learn.run_observed(
-        [_scn(CHAMELEON, "me", FAST, executor="pallas")])
+    (got,) = learn.run_observed([_scn(CHAMELEON, "me", FAST)])
     assert _leaves_equal(ref.obs, got.obs)
+
+
+@pytest.mark.parametrize("path", ["run_fleet", "run_fleet_online",
+                                  "wave_runner", "sharded_runner"])
+def test_explicit_pallas_is_refused_where_the_kernel_cannot_run(path):
+    """Paths the fused kernel does not reach raise on an explicit pallas
+    request; none swaps it for another executor."""
+    req = fleet.TransferRequest(arrival_s=0.0, datasets=FAST,
+                                controller="eemt", profile=CHAMELEON,
+                                name="solo", total_s=240.0)
+    hosts = fleet.host_pool(1)
+    k = _scenario._prepare(_scn(CHAMELEON, "eemt", FAST)).key
+    args = (k.ctrl_code, k.env_code, k.cpu)
+    calls = {
+        "run_fleet": lambda: fleet.run_fleet([req], hosts,
+                                             executor="pallas"),
+        "run_fleet_online": lambda: fleet.run_fleet_online(
+            [req], hosts, executor="pallas"),
+        "wave_runner": lambda: engine.get_wave_runner(
+            *args, 50, 0.1, k.ctrl_every, executor="pallas",
+            n_partitions=2),
+        "sharded_runner": lambda: engine.get_sharded_runner(
+            *args, k.n_steps, 0.1, k.ctrl_every, tuple(jax.devices()),
+            executor="pallas"),
+    }
+    with pytest.raises(ValueError, match="pallas"):
+        calls[path]()
 
 
 # ------------------------------------------------- sharded blocked waves ---

@@ -16,21 +16,29 @@ import jax
 assert jax.device_count() == 4, jax.devices()
 
 from repro import fleet
-from repro.core.types import CHAMELEON, DatasetSpec
+from repro.core import engine
+from repro.core.types import CHAMELEON, MIXED, DatasetSpec
 
 BIG = (DatasetSpec("a", 2000, 4000.0, 2.0),)
-reqs = [fleet.TransferRequest(arrival_s=0.0, datasets=BIG,
-                              controller="eemt", profile=CHAMELEON,
-                              name=f"t{i}", total_s=300.0)
-        for i in range(6)]
+MIX = tuple(DatasetSpec(d.name, d.num_files // 100, d.total_mb / 100,
+                        d.avg_file_mb) for d in MIXED)
 hosts = fleet.host_pool(6, nic_mbps=1e9)
-multi = fleet.run_fleet(reqs, hosts, wave_s=5.0, dt=0.1)
-single = fleet.run_fleet(reqs, hosts, wave_s=5.0, dt=0.1,
-                         devices=jax.devices()[:1])
-assert multi.completed == len(reqs)
-for m, s in zip(multi.transfers, single.transfers):
-    assert (m.time_s, m.energy_j, m.completed) == \
-        (s.time_s, s.energy_j, s.completed), (m, s)
+# 6 lanes -> bucket 8 over 4 devices; 4 lanes -> one per device (the
+# multi-partition datasets are where a batch-width-dependent partition sum
+# would show); 3 lanes -> fewer lanes than devices, so unsharded.
+for n, datasets, sharded in ((6, BIG, 1), (4, MIX, 1), (3, MIX, 0)):
+    reqs = [fleet.TransferRequest(arrival_s=0.0, datasets=datasets,
+                                  controller="eemt", profile=CHAMELEON,
+                                  name=f"t{i}", total_s=300.0)
+            for i in range(n)]
+    engine.clear_runner_caches()
+    multi = fleet.run_fleet(reqs, hosts, wave_s=5.0, dt=0.1)
+    assert engine.runner_cache_sizes()["sharded_wave"] == sharded, n
+    single = fleet.run_fleet(reqs, hosts, wave_s=5.0, dt=0.1,
+                             devices=jax.devices()[:1])
+    assert multi.completed == len(reqs)
+    for m, s in zip(multi.transfers, single.transfers):
+        assert m == s, (m, s)           # frozen dataclass: bit-exact
 print("SHARDED-FLEET-OK")
 """
 

@@ -13,8 +13,7 @@ from repro.ckpt import restore_latest, save
 from repro.configs import ARCHS, get_config, get_smoke_config
 from repro.data import SyntheticSource, batches
 from repro.distributed import collectives
-from repro.distributed.sharding import (param_specs, shard_map,
-                                        spec_for)
+from repro.distributed.sharding import param_specs, spec_for
 from repro.models import build
 from repro.optim import AdamWConfig
 from repro.train import init_train_state
@@ -141,7 +140,7 @@ def test_chunked_psum_matches_psum():
     def f(x):
         return collectives.chunked_psum(x, "x", num_chunks=4)
 
-    y = shard_map(f, mesh=mesh, in_specs=P(), out_specs=P())(x)
+    y = jax.shard_map(f, mesh=mesh, in_specs=P(), out_specs=P())(x)
     np.testing.assert_allclose(np.asarray(y), np.asarray(x))
 
 

@@ -10,7 +10,6 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-from repro.distributed.sharding import set_mesh
 from repro.models import layers as L  # noqa: E402
 from repro.models.common import ModelConfig, MoEConfig  # noqa: E402
 
@@ -34,7 +33,7 @@ def _setup(E=8, k=2, d=32, ff=64):
 def test_a2a_matches_gmm_with_ample_capacity(mesh):
     from repro.distributed.moe_a2a import moe_a2a
     cfg, p, x = _setup()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         y_ref, _ = L.moe_gmm(cfg, p, x)
         y_a2a, _ = jax.jit(
             lambda p, x: moe_a2a(cfg, p, x, capacity_factor=8.0))(p, x)
@@ -45,7 +44,7 @@ def test_a2a_matches_gmm_with_ample_capacity(mesh):
 def test_a2a_tight_capacity_drops_but_stays_finite(mesh):
     from repro.distributed.moe_a2a import moe_a2a
     cfg, p, x = _setup()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         y, aux = jax.jit(
             lambda p, x: moe_a2a(cfg, p, x, capacity_factor=0.5))(p, x)
     assert not bool(jnp.isnan(y).any())
@@ -60,7 +59,7 @@ def test_a2a_differentiable(mesh):
         y, aux = moe_a2a(cfg, p, x, capacity_factor=4.0)
         return jnp.sum(jnp.square(y.astype(jnp.float32))) + aux
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         g = jax.jit(jax.grad(loss))(p, x)
     gn = sum(float(jnp.sum(jnp.abs(v))) for v in jax.tree.leaves(g))
     assert np.isfinite(gn) and gn > 0

@@ -96,8 +96,10 @@ def test_slot_pool_fifo_recycling():
 
 # ------------------------------------------------------------- ExactSum --
 
+# Width-32 draws (the fold adds float32 state components) need bounds that
+# float32 represents exactly: 2**40 (~1.1e12) is, 1e12 is not.
 @settings(max_examples=60, deadline=None)
-@given(st.lists(st.floats(min_value=-1e12, max_value=1e12,
+@given(st.lists(st.floats(min_value=-2.0**40, max_value=2.0**40,
                           allow_nan=False, allow_infinity=False,
                           width=32),
                 min_size=0, max_size=200),

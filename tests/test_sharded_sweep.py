@@ -27,26 +27,48 @@ assert jax.device_count() == 4, jax.devices()
 
 import numpy as np
 from repro import api
+from repro.core import engine
 from repro.core.types import CHAMELEON, DatasetSpec
 
 FAST = (DatasetSpec("a", 200, 400.0, 2.0),
         DatasetSpec("b", 10, 600.0, 60.0))
-# 6 lanes in one group -> padded to 8 across 4 devices.
-scenarios = [api.Scenario(profile=CHAMELEON, datasets=FAST,
-                          controller=api.make_controller("eemt", max_ch=mc),
-                          total_s=60.0, dt=0.25)
-             for mc in (4, 8, 16, 32, 64, 48)]
-assert api.group_count(scenarios) == 1
-swept = api.sweep(scenarios)
-assert len(swept) == len(scenarios)
-for sc, batched in zip(scenarios, swept):
-    single = api.run(sc)             # unbatched, single-device path
-    assert single.completed == batched.completed
-    assert single.time_s == batched.time_s, (single.time_s, batched.time_s)
-    assert single.energy_j == batched.energy_j
-    assert batched.metrics.tput_mbps.shape == single.metrics.tput_mbps.shape
+
+
+def group(max_chs):
+    return [api.Scenario(profile=CHAMELEON, datasets=FAST,
+                         controller=api.make_controller("eemt", max_ch=mc),
+                         total_s=60.0, dt=0.25)
+            for mc in max_chs]
+
+
+# 6 lanes in one group -> padded to 8 across 4 devices; 4 lanes -> one
+# per device; 3 lanes -> fewer lanes than devices, so unsharded.
+for scenarios, sharded in ((group((4, 8, 16, 32, 64, 48)), 1),
+                           (group((4, 8, 16, 32)), 1),
+                           (group((4, 8, 16)), 0)):
+    assert api.group_count(scenarios) == 1
+    engine.clear_runner_caches()
+    swept = api.sweep(scenarios)
+    assert engine.runner_cache_sizes()["sharded"] == sharded
+    assert len(swept) == len(scenarios)
+    for sc, batched in zip(scenarios, swept):
+        single = api.run(sc)             # unbatched, single-device path
+        assert single.completed == batched.completed
+        assert single.time_s == batched.time_s, (single.time_s,
+                                                 batched.time_s)
+        assert single.energy_j == batched.energy_j
+        assert single.avg_tput_MBps == batched.avg_tput_MBps
+        assert (batched.metrics.tput_mbps.shape
+                == single.metrics.tput_mbps.shape)
 print("SHARDED-SWEEP-OK")
 """
+
+
+@pytest.mark.parametrize("lanes,ndev,sharded", [
+    (6, 4, True), (4, 4, True), (3, 4, False), (8, 1, False), (8, 0, False)])
+def test_should_shard_needs_a_lane_per_device(lanes, ndev, sharded):
+    devices = tuple(range(ndev)) if ndev else None
+    assert shd.should_shard(lanes, devices) == sharded
 
 
 def test_pad_batch_pads_by_repeating_last_row():

@@ -1,0 +1,8 @@
+"""Host ms a grid pass spends in the program's ``sweep.postprocess``
+spans (``repro.api.scenario``)."""
+from bench import spans
+
+
+def read(ctx):
+    return spans.leaf_ms(spans.records(), "sweep.postprocess",
+                         ctx["counters"]["units"])

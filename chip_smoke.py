@@ -60,22 +60,12 @@ GRID_METRICS = ("completed", "time_s", "energy_j", "avg_tput_MBps",
                 "avg_power_w")
 
 
-class _CompileClock:
-    """Seconds JAX spends tracing, lowering and compiling (or fetching
-    compiled programs from the persistent cache), read off JAX's own
-    compile events."""
-
-    EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
-              "/jax/core/compile/jaxpr_to_mlir_module_duration",
-              "/jax/core/compile/backend_compile_duration")
-
-    def __init__(self, jax):
-        self.total = 0.0
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
-
-    def _on_event(self, event, duration, **_):
-        if event in self.EVENTS:
-            self.total += duration
+def compile_clock() -> float:
+    """Seconds JAX has spent in this process tracing, lowering and
+    compiling (or fetching compiled programs from the persistent cache),
+    as ``repro.obs`` counts them from JAX's own compile events."""
+    from repro import obs
+    return sum(obs.counters()["compile_s"].values())
 
 
 def check(cond: bool, what: str) -> None:
@@ -128,17 +118,17 @@ def _compare_to_cpu(name: str, got: dict, want: dict) -> float:
     return dev
 
 
-def grid_phase(jax, api, clock, chip, cpu, exp) -> dict:
+def grid_phase(jax, api, chip, cpu, exp) -> dict:
     cells = exp.cells()
     scenarios = [c.scenario for c in cells]
     names = ["/".join(c.labels[a] for a in exp.axis_names) for c in cells]
 
     results: list = []
-    c0, t0 = clock.total, time.perf_counter()
+    c0, t0 = compile_clock(), time.perf_counter()
     report = exp.run(cells=cells,
                      sweeper=_recording_sweep(api, (chip,), results))
     wall_s = time.perf_counter() - t0
-    compile_s = clock.total - c0
+    compile_s = compile_clock() - c0
     check(len(results) == len(cells) == len(report),
           f"{len(results)} results for {len(cells)} cells")
     incomplete = [n for n, r in zip(names, results) if not r.completed]
@@ -190,7 +180,7 @@ def _fleet_run(fleet, trace, hosts, capacity: int, **kw):
                                   pool_capacity=capacity, **kw)
 
 
-def fleet_phase(jax, fleet, faults_mod, clock, chip, cpu, trace,
+def fleet_phase(jax, fleet, faults_mod, chip, cpu, trace,
                 hosts) -> dict:
     # The host slot budgets bound the in-flight population, so a pool of
     # that capacity never makes a request wait for a slot.
@@ -198,12 +188,12 @@ def fleet_phase(jax, fleet, faults_mod, clock, chip, cpu, trace,
     capacity = sum(h.slots for h in hosts)
     offered = math.fsum(d.total_mb for r in trace for d in r.datasets)
 
-    c0, t0 = clock.total, time.perf_counter()
+    c0, t0 = compile_clock(), time.perf_counter()
     with jax.default_device(chip):
         rep = _fleet_run(fleet, trace, hosts, capacity,
                          faults=faults_mod.FaultSchedule())
     wall_s = time.perf_counter() - t0
-    compile_s = clock.total - c0
+    compile_s = compile_clock() - c0
     check(rep.fold.transfers == len(trace) and rep.dropped == 0,
           f"{rep.fold.transfers} of {len(trace)} transfers retired, "
           f"{rep.dropped} dropped")
@@ -273,15 +263,15 @@ def _check_same_transfers(what: str, against: str, a, b) -> None:
           f"({what}, {against}): {differ[:2]}")
 
 
-def grid_sharded_phase(jax, api, clock, chips, exp) -> dict:
+def grid_sharded_phase(jax, api, chips, exp) -> dict:
     """The grid through the sharded sweep runner, bit for bit against one
     chip."""
     cells = exp.cells()
     multi: list = []
-    c0, t0 = clock.total, time.perf_counter()
+    c0, t0 = compile_clock(), time.perf_counter()
     exp.run(cells=cells, sweeper=_recording_sweep(api, chips, multi))
     wall_s = time.perf_counter() - t0
-    compile_s = clock.total - c0
+    compile_s = compile_clock() - c0
     single: list = []
     exp.run(cells=cells, sweeper=_recording_sweep(api, chips[:1], single))
     check(all(r.completed for r in multi), "sharded grid: a cell did not "
@@ -298,17 +288,17 @@ def grid_sharded_phase(jax, api, clock, chips, exp) -> dict:
             "max_rel_dev_vs_one_chip": 0.0}
 
 
-def fleet_sharded_phase(jax, fleet, sharding, clock, chips, trace,
+def fleet_sharded_phase(jax, fleet, sharding, chips, trace,
                         hosts) -> dict:
     """The fleet with a mesh over the chips, bit for bit against one
     chip."""
     capacity = sum(h.slots for h in hosts)
     mesh = sharding.MeshConfig(1, len(chips))
-    c0, t0 = clock.total, time.perf_counter()
+    c0, t0 = compile_clock(), time.perf_counter()
     sharded = _fleet_run(fleet, trace, hosts, capacity, mesh=mesh,
                          track_transfers=True)
     wall_s = time.perf_counter() - t0
-    compile_s = clock.total - c0
+    compile_s = compile_clock() - c0
     with jax.default_device(chips[0]):
         one = _fleet_run(fleet, trace, hosts, capacity,
                          track_transfers=True)
@@ -319,11 +309,11 @@ def fleet_sharded_phase(jax, fleet, sharding, clock, chips, trace,
 
     # The offline driver shards each wave of at least one lane per chip.
     prefix = trace[:PREFIX]
-    c1, t1 = clock.total, time.perf_counter()
+    c1, t1 = compile_clock(), time.perf_counter()
     offline = fleet.run_fleet(prefix, hosts, wave_s=WAVE_S, dt=DT,
                               devices=chips)
     offline_wall_s = time.perf_counter() - t1
-    offline_compile_s = clock.total - c1
+    offline_compile_s = compile_clock() - c1
     offline_one = fleet.run_fleet(prefix, hosts, wave_s=WAVE_S, dt=DT,
                                   devices=chips[:1])
     _check_same_transfers("sharded offline fleet", "one chip", offline,
@@ -378,17 +368,16 @@ def main(argv=None) -> None:
     from repro.workloads import faults
 
     use_compile_cache()
-    clock = _CompileClock(jax)
     exp = fig2.experiment(smoke=False)
     trace, hosts = fleet_bench.build(smoke=False)
 
     if args.chips == 1:
-        report(grid_phase(jax, api, clock, chips[0], cpu, exp))
-        report(fleet_phase(jax, fleet, faults, clock, chips[0], cpu, trace,
+        report(grid_phase(jax, api, chips[0], cpu, exp))
+        report(fleet_phase(jax, fleet, faults, chips[0], cpu, trace,
                            hosts))
     else:
-        report(grid_sharded_phase(jax, api, clock, chips, exp))
-        report(fleet_sharded_phase(jax, fleet, sharding, clock, chips, trace,
+        report(grid_sharded_phase(jax, api, chips, exp))
+        report(fleet_sharded_phase(jax, fleet, sharding, chips, trace,
                                    hosts))
     print(json.dumps({"ok": True, "device": {
         "platform": chips[0].platform, "kind": chips[0].device_kind,

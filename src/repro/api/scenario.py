@@ -26,6 +26,7 @@ from typing import Any, NamedTuple, Optional, Sequence
 import jax
 import numpy as np
 
+from repro import obs
 from repro.core import engine
 from repro.core.engine import ScanInputs, TransferResult
 from repro.core.types import CpuProfile, NetworkProfile
@@ -227,14 +228,35 @@ def _merged_partition_counts(keys) -> dict:
     return {k: p_max[k._replace(n_partitions=0)] for k in keys}
 
 
+def _fetch(out, batch: Optional[int] = None):
+    """Wait for a runner's ``(sim, ts, metrics)``, then copy ``sim`` and
+    the per-tick metrics to the host, cut to ``batch`` lanes if given."""
+    with obs.span("sweep.wait"):
+        jax.block_until_ready(out)
+    sim, _, metrics = out
+    if batch is None:
+        host = np.asarray
+        n_bytes = sum(x.nbytes for x in jax.tree.leaves(metrics))
+    else:
+        def host(x):
+            return np.asarray(x)[:batch]
+        n_bytes = sum(x.nbytes // x.shape[0] * batch
+                      for x in jax.tree.leaves(metrics))
+    with obs.span("sweep.fetch", bytes=n_bytes):
+        return jax.tree.map(host, sim), jax.tree.map(host, metrics)
+
+
 def _run_prepared(prep: _Prepared) -> TransferResult:
     """Execute one prepared scenario on the unbatched cached runner."""
     k = prep.key
-    runner = engine.get_runner(k.ctrl_code, k.env_code, k.cpu, k.n_steps,
-                               k.dt, k.ctrl_every, batched=False,
-                               executor=k.executor)
-    sim, _, metrics = runner(prep.inputs)
-    return _postprocess(sim, metrics, prep)
+    with obs.span("sweep.launch"):
+        runner = engine.get_runner(k.ctrl_code, k.env_code, k.cpu,
+                                   k.n_steps, k.dt, k.ctrl_every,
+                                   batched=False, executor=k.executor)
+        out = runner(prep.inputs)
+    sim, metrics = _fetch(out)
+    with obs.span("sweep.postprocess"):
+        return _postprocess(sim, metrics, prep)
 
 
 def run(scenario: Scenario) -> TransferResult:
@@ -249,21 +271,20 @@ def _run_group(key: _GroupKey, stacked, batch: int, devices):
     axis of exactly ``batch`` (device padding stripped).
     """
     from repro.distributed import sharding as shd
-    if shd.should_shard(batch, devices):
-        stacked, _ = shd.pad_batch(stacked, len(devices))
-        mesh = shd.batch_mesh(devices)
-        runner = engine.get_sharded_runner(
-            key.ctrl_code, key.env_code, key.cpu, key.n_steps, key.dt,
-            key.ctrl_every, tuple(devices), executor=key.executor)
-        sim, _, metrics = runner(shd.shard_batch(stacked, mesh))
-    else:
-        runner = engine.get_runner(key.ctrl_code, key.env_code, key.cpu,
-                                   key.n_steps, key.dt, key.ctrl_every,
-                                   batched=True, executor=key.executor)
-        sim, _, metrics = runner(stacked)
-    sim = jax.tree.map(lambda x: np.asarray(x)[:batch], sim)
-    metrics = jax.tree.map(lambda x: np.asarray(x)[:batch], metrics)
-    return sim, metrics
+    with obs.span("sweep.launch"):
+        if shd.should_shard(batch, devices):
+            stacked, _ = shd.pad_batch(stacked, len(devices))
+            mesh = shd.batch_mesh(devices)
+            runner = engine.get_sharded_runner(
+                key.ctrl_code, key.env_code, key.cpu, key.n_steps, key.dt,
+                key.ctrl_every, tuple(devices), executor=key.executor)
+            out = runner(shd.shard_batch(stacked, mesh))
+        else:
+            runner = engine.get_runner(key.ctrl_code, key.env_code, key.cpu,
+                                       key.n_steps, key.dt, key.ctrl_every,
+                                       batched=True, executor=key.executor)
+            out = runner(stacked)
+    return _fetch(out, batch)
 
 
 def sweep(scenarios: Sequence[Scenario], *,
@@ -281,6 +302,11 @@ def sweep(scenarios: Sequence[Scenario], *,
     through a ``shard_map`` runner with donated input buffers; on a single
     device — or with an explicitly empty ``devices`` sequence — the plain
     vmapped runner is used and results are identical.
+
+    Each call opens the host spans of :mod:`repro.obs`: ``sweep``, with
+    ``sweep.prepare`` and one ``sweep.group`` per executable holding
+    ``sweep.launch``, ``sweep.wait``, ``sweep.fetch`` and
+    ``sweep.postprocess``.
     """
     if devices is None:
         devices = jax.devices()
@@ -288,31 +314,41 @@ def sweep(scenarios: Sequence[Scenario], *,
     # here so the single-device fallback is a deliberate branch, not an
     # accident of the len(devices) > 1 guard.
     devices = tuple(devices) or None
-    prepared = [_prepare(sc) for sc in scenarios]
-    # Merge across dataset counts: pad each scenario to the widest partition
-    # axis among the scenarios it could share an executable with.  A few
-    # dead zero-byte lanes collapse the executable count, and compile time
-    # dominates a cold sweep; scenarios whose groups can never merge are
-    # left unpadded.
-    merged = _merged_partition_counts([p.key for p in prepared])
-    prepared = [_pad_partitions(p, merged[p.key]) for p in prepared]
-    groups: dict[_GroupKey, list[int]] = defaultdict(list)
-    for i, prep in enumerate(prepared):
-        groups[prep.key].append(i)
+    with obs.span("sweep", scenarios=len(scenarios)) as root:
+        with obs.span("sweep.prepare"):
+            prepared = [_prepare(sc) for sc in scenarios]
+            # Merge across dataset counts: pad each scenario to the widest
+            # partition axis among the scenarios it could share an
+            # executable with.  A few dead zero-byte lanes collapse the
+            # executable count, and compile time dominates a cold sweep;
+            # scenarios whose groups can never merge are left unpadded.
+            merged = _merged_partition_counts([p.key for p in prepared])
+            prepared = [_pad_partitions(p, merged[p.key]) for p in prepared]
+            groups: dict[_GroupKey, list[int]] = defaultdict(list)
+            for i, prep in enumerate(prepared):
+                groups[prep.key].append(i)
+        root.annotate(groups=len(groups))
 
-    results: list[Optional[TransferResult]] = [None] * len(prepared)
-    for key, idxs in groups.items():
-        if len(idxs) == 1:
-            results[idxs[0]] = _run_prepared(prepared[idxs[0]])
-            continue
-        stacked = jax.tree.map(lambda *xs: np.stack(xs),
-                               *[prepared[i].inputs for i in idxs])
-        sim_np, metrics_np = _run_group(key, stacked, len(idxs), devices)
-        for b, i in enumerate(idxs):
-            results[i] = _postprocess(
-                jax.tree.map(lambda x: x[b], sim_np),
-                jax.tree.map(lambda x: x[b], metrics_np),
-                prepared[i])
+        results: list[Optional[TransferResult]] = [None] * len(prepared)
+        for key, idxs in groups.items():
+            with obs.span("sweep.group", lanes=len(idxs),
+                          n_steps=key.n_steps,
+                          partitions=key.n_partitions):
+                if len(idxs) == 1:
+                    results[idxs[0]] = _run_prepared(prepared[idxs[0]])
+                    continue
+                with obs.span("sweep.launch"):
+                    stacked = jax.tree.map(
+                        lambda *xs: np.stack(xs),
+                        *[prepared[i].inputs for i in idxs])
+                sim_np, metrics_np = _run_group(key, stacked, len(idxs),
+                                                devices)
+                with obs.span("sweep.postprocess"):
+                    for b, i in enumerate(idxs):
+                        results[i] = _postprocess(
+                            jax.tree.map(lambda x: x[b], sim_np),
+                            jax.tree.map(lambda x: x[b], metrics_np),
+                            prepared[i])
     return results
 
 

@@ -279,6 +279,7 @@ def make_step_fn(controller, env, cpu: CpuProfile, inp: ScanInputs, *,
     existed — zero overhead when disabled.
     """
 
+    @jax.named_scope("engine.tick")
     def step(carry, xs):
         sim, ts = carry
         step_idx, bw_scale = xs
@@ -448,6 +449,7 @@ def build_core(controller, env, cpu: CpuProfile, *, n_steps: int, dt: float,
 
         bw = jnp.pad(inp.bw, ((0, padded - n_steps),))
 
+        @jax.named_scope("engine.store")
         def store(buf, m, start):
             return jax.tree.map(
                 lambda b, x: jax.lax.dynamic_update_slice(
@@ -467,6 +469,7 @@ def build_core(controller, env, cpu: CpuProfile, *, n_steps: int, dt: float,
                     k < n_chunks,
                     partition_sum(f32[..., :n_partitions]) > 0.0)
 
+            @jax.named_scope("engine.chunk")
             def body(carry):
                 k, f32, i32, buf = carry
                 start = k * chunk
@@ -487,6 +490,7 @@ def build_core(controller, env, cpu: CpuProfile, *, n_steps: int, dt: float,
                 return jnp.logical_and(k < n_chunks,
                                        partition_sum(sim.remaining_mb) > 0.0)
 
+            @jax.named_scope("engine.chunk")
             def body(carry):
                 k, state, buf = carry
                 start = k * chunk

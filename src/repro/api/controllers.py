@@ -238,11 +238,11 @@ class StaticBaselineController:
                 tuple(specs), profile, cpu)
             pp, par, cc, cores, freq_idx = _freeze_params(built.params)
         params = TransferParams(
-            pp=jnp.asarray(pp, jnp.float32),
-            par=jnp.asarray(par, jnp.float32),
-            cc=jnp.asarray(cc, jnp.float32),
-            cores=jnp.asarray(cores, jnp.int32),
-            freq_idx=jnp.asarray(freq_idx, jnp.int32),
+            pp=np.asarray(pp, np.float32),
+            par=np.asarray(par, np.float32),
+            cc=np.asarray(cc, np.float32),
+            cores=np.asarray(cores, np.int32),
+            freq_idx=np.asarray(freq_idx, np.int32),
         )
         state = tuners.init_tuner_state(float(sum(cc)), cores, freq_idx)
         return ControllerInit(params, state, tuple(specs),

@@ -19,7 +19,7 @@ from __future__ import annotations
 import dataclasses
 import math
 
-import jax.numpy as jnp
+import numpy as np
 
 from .types import (CpuProfile, NetworkProfile,
                     TransferParams)
@@ -39,11 +39,11 @@ class StaticController:
 
 def _mk(name, pp, par, cc, cores, freq_idx) -> StaticController:
     p = TransferParams(
-        pp=jnp.asarray(pp, jnp.float32),
-        par=jnp.asarray(par, jnp.float32),
-        cc=jnp.asarray(cc, jnp.float32),
-        cores=jnp.asarray(cores, jnp.int32),
-        freq_idx=jnp.asarray(freq_idx, jnp.int32),
+        pp=np.asarray(pp, np.float32),
+        par=np.asarray(par, np.float32),
+        cc=np.asarray(cc, np.float32),
+        cores=np.asarray(cores, np.int32),
+        freq_idx=np.asarray(freq_idx, np.int32),
     )
     return StaticController(name=name, params=p)
 

@@ -183,8 +183,9 @@ class ScanInputs(NamedTuple):
 
         Leaves built here are host-side (numpy) so batch stacking stays on
         the host; ``pp``/``par``/``state0`` pass through as the controller
-        produced them (possibly device arrays — ``_prepare`` normalizes with
-        ``np.asarray`` before stacking).
+        produced them: numpy for every built-in controller, and whatever a
+        third-party ``init`` returns, which ``_prepare`` normalizes with
+        ``np.asarray`` before stacking.
         """
         return cls(
             net=NetParams.from_profile(profile),

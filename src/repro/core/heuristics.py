@@ -9,13 +9,26 @@ Runs once, before the transfer starts.  Mirrors the paper line-by-line:
     9:  numChannels = ceil(bandwidth / tputChannel)
     10-13: ccLevel_i = ceil(weight_i * numChannels),  weight_i ∝ partition bytes
     14-20: SLA -> (numActiveCores, coreFrequency)
+
+The set-up (``split_large_files``, ``initialize``) is a handful of scalar
+float32 operations on Python numbers, computed on the host in numpy: it
+touches no device.  Each ``ceil`` is of the quotient rounded to float32,
+the precision the engine runs in.  ``redistribute_channels`` runs inside
+the jitted tick and stays ``jnp``.
 """
 from __future__ import annotations
 
 import jax.numpy as jnp
+import numpy as np
 
 from .types import (CpuProfile, DatasetSpec, NetworkProfile, SLA, SLAPolicy,
                     TransferParams, partition_sum)
+
+
+def _ceil32(q: float) -> float:
+    """``ceil`` of ``q`` rounded to float32 (not of the float64 ``q``: the
+    two differ where ``q`` lies within a float32 ulp above an integer)."""
+    return float(np.ceil(np.float32(q)))
 
 
 def split_large_files(spec: DatasetSpec, bdp_mb: float) -> tuple[DatasetSpec, float]:
@@ -25,7 +38,7 @@ def split_large_files(spec: DatasetSpec, bdp_mb: float) -> tuple[DatasetSpec, fl
     each chunk rides its own sub-stream and exactly fills the channel.
     """
     if spec.avg_file_mb > bdp_mb and bdp_mb > 0:
-        par = float(int(jnp.ceil(spec.avg_file_mb / bdp_mb)))
+        par = _ceil32(spec.avg_file_mb / bdp_mb)
         chunk = spec.avg_file_mb / par
         spec = DatasetSpec(
             name=spec.name,
@@ -55,7 +68,7 @@ def initialize(
     chunked = tuple(chunked)
 
     # line 6: pipelining amortizes per-file RTTs for small files.
-    pp = [max(1.0, float(jnp.ceil(bdp / max(s.avg_file_mb, 1e-6)))) for s in chunked]
+    pp = [max(1.0, _ceil32(bdp / max(s.avg_file_mb, 1e-6))) for s in chunked]
     # Cap pipelining: beyond ~the per-channel queue there is no extra win.
     pp = [min(p_, 128.0) for p_ in pp]
 
@@ -65,13 +78,17 @@ def initialize(
     if sla.policy == SLAPolicy.TARGET_THROUGHPUT and sla.target_tput_mbps > 0:
         goal_mbps = min(goal_mbps, sla.target_tput_mbps)
     tput_channel = profile.avg_window_mb / profile.rtt_s
-    num_channels = float(jnp.ceil(goal_mbps / max(tput_channel, 1e-6)))
+    num_channels = _ceil32(goal_mbps / max(tput_channel, 1e-6))
 
-    # lines 10-13: distribute channels by partition weight.
-    sizes = jnp.array([s.total_mb for s in chunked], jnp.float32)
-    weights = sizes / jnp.maximum(jnp.sum(sizes), 1e-6)
-    cc = jnp.ceil(weights * num_channels)
-    cc = jnp.maximum(cc, 1.0)
+    # lines 10-13: distribute channels by partition weight, in float32;
+    # the total adds in index order.
+    sizes = np.array([s.total_mb for s in chunked], np.float32)
+    total = np.float32(0.0)
+    for size in sizes:
+        total += size
+    weights = sizes / max(total, np.float32(1e-6))
+    cc = np.maximum(np.ceil(weights * np.float32(num_channels)),
+                    np.float32(1.0))
 
     # lines 14-20: SLA-dependent CPU operating point.
     if sla.policy == SLAPolicy.MIN_ENERGY:
@@ -80,11 +97,11 @@ def initialize(
         cores, freq_idx = cpu.num_cores, 0
 
     params = TransferParams(
-        pp=jnp.asarray(pp, jnp.float32),
-        par=jnp.asarray(par, jnp.float32),
-        cc=cc.astype(jnp.float32),
-        cores=jnp.asarray(cores, jnp.int32),
-        freq_idx=jnp.asarray(freq_idx, jnp.int32),
+        pp=np.asarray(pp, np.float32),
+        par=np.asarray(par, np.float32),
+        cc=cc,
+        cores=np.asarray(cores, np.int32),
+        freq_idx=np.asarray(freq_idx, np.int32),
     )
     return params, chunked
 

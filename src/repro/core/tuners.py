@@ -17,6 +17,7 @@ from __future__ import annotations
 from typing import NamedTuple
 
 import jax.numpy as jnp
+import numpy as np
 
 from . import fsm
 from .load_control import load_control
@@ -35,15 +36,18 @@ class Measurement(NamedTuple):
 
 
 def init_tuner_state(num_ch0, cores0, freq_idx0) -> TunerState:
-    z = jnp.zeros((), jnp.float32)
+    """The Slow-Start state as host (numpy) 0-d arrays: building it touches
+    no device, and the updates below take numpy leaves as they are."""
     return TunerState(
-        fsm=jnp.asarray(fsm.SLOW_START, jnp.int32),
-        num_ch=jnp.asarray(num_ch0, jnp.float32),
-        prev_num_ch=jnp.asarray(num_ch0, jnp.float32),
-        ref=z,
-        cores=jnp.asarray(cores0, jnp.int32),
-        freq_idx=jnp.asarray(freq_idx0, jnp.int32),
-        acc_mb=z, acc_j=z, acc_s=z,
+        fsm=np.asarray(fsm.SLOW_START, np.int32),
+        num_ch=np.asarray(num_ch0, np.float32),
+        prev_num_ch=np.asarray(num_ch0, np.float32),
+        ref=np.zeros((), np.float32),
+        cores=np.asarray(cores0, np.int32),
+        freq_idx=np.asarray(freq_idx0, np.int32),
+        acc_mb=np.zeros((), np.float32),
+        acc_j=np.zeros((), np.float32),
+        acc_s=np.zeros((), np.float32),
     )
 
 

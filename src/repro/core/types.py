@@ -172,13 +172,15 @@ class TransferParams(NamedTuple):
 
     ``cc`` is per-partition (concurrency per dataset); ``pp``/``par`` are
     per-partition as well since Algorithm 1 derives them from avg file size.
+    Algorithm 1 and the static baselines build them on the host, as numpy
+    arrays; the engine receives them inside its traced inputs.
     """
 
-    pp: jnp.ndarray        # [P] pipelining depth per partition (float)
-    par: jnp.ndarray       # [P] parallelism (chunks/file) per partition
-    cc: jnp.ndarray        # [P] concurrent channels per partition
-    cores: jnp.ndarray     # [] active core count (int32)
-    freq_idx: jnp.ndarray  # [] index into freq_levels_ghz (int32)
+    pp: np.ndarray         # [P] float32 pipelining depth per partition
+    par: np.ndarray        # [P] float32 parallelism (chunks/file)
+    cc: np.ndarray         # [P] float32 concurrent channels per partition
+    cores: np.ndarray      # [] int32 active core count
+    freq_idx: np.ndarray   # [] int32 index into freq_levels_ghz
 
 
 def _fold_partitions(x):

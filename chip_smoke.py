@@ -49,6 +49,8 @@ import sys
 import time
 from pathlib import Path
 
+import numpy as np
+
 ROOT = Path(__file__).resolve().parent
 
 DT = 0.1             # the paper's tick
@@ -95,11 +97,21 @@ def _recording_sweep(api, devices, into: list):
     return sweeper
 
 
-def _same_bits(jax, a, b) -> bool:
-    """Two TransferResults agree bit for bit, per-tick traces included."""
-    return _scalars(a) == _scalars(b) and all(
-        (x == y).all() for x, y in zip(jax.tree.leaves(a.metrics),
-                                       jax.tree.leaves(b.metrics)))
+def _completion_tick(r) -> int:
+    """The tick during which a transfer drained, -1 if it did not: where
+    ``api.run``'s ``done`` trace first turns True, or, for a sweep result
+    (which carries no traces), the tick its ``time_s`` ends."""
+    if not r.completed:
+        return -1
+    if r.metrics is not None:
+        return int(np.argmax(r.metrics.done))
+    return round(r.time_s / DT) - 1
+
+
+def _same_bits(a, b) -> bool:
+    """Two TransferResults agree bit for bit, completion tick included."""
+    return (_scalars(a) == _scalars(b)
+            and _completion_tick(a) == _completion_tick(b))
 
 
 def _compare_to_cpu(name: str, got: dict, want: dict) -> float:
@@ -153,7 +165,7 @@ def grid_phase(jax, api, chip, cpu, exp) -> dict:
     solo = _scalars(solo_res)
     max_dev = max(max_dev, _compare_to_cpu(f"api.run {names[one]}", solo,
                                            ref_rows[one]))
-    check(_same_bits(jax, solo_res, results[one]),
+    check(_same_bits(solo_res, results[one]),
           f"api.run {names[one]} {solo} differs from its sweep-group lane "
           f"{chip_rows[one]} on the chip")
 
@@ -263,7 +275,7 @@ def _check_same_transfers(what: str, against: str, a, b) -> None:
           f"({what}, {against}): {differ[:2]}")
 
 
-def grid_sharded_phase(jax, api, chips, exp) -> dict:
+def grid_sharded_phase(api, chips, exp) -> dict:
     """The grid through the sharded sweep runner, bit for bit against one
     chip."""
     cells = exp.cells()
@@ -278,7 +290,7 @@ def grid_sharded_phase(jax, api, chips, exp) -> dict:
                                            "complete")
     differ = [(c.labels, rel_dev(m.energy_j, s.energy_j))
               for c, m, s in zip(cells, multi, single)
-              if not _same_bits(jax, m, s)]
+              if not _same_bits(m, s)]
     check(not differ, f"sharded grid differs from one chip in "
                       f"{len(differ)} cells (cell, energy deviation): "
                       f"{differ[:4]}")
@@ -376,7 +388,7 @@ def main(argv=None) -> None:
         report(fleet_phase(jax, fleet, faults, chips[0], cpu, trace,
                            hosts))
     else:
-        report(grid_sharded_phase(jax, api, chips, exp))
+        report(grid_sharded_phase(api, chips, exp))
         report(fleet_sharded_phase(jax, fleet, sharding, chips, trace,
                                    hosts))
     print(json.dumps({"ok": True, "device": {

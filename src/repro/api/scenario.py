@@ -11,8 +11,8 @@ batch has drained, instead of burning the full padded ``total_s`` horizon.
 
 On hosts with more than one accelerator device, groups with at least one
 lane per device are additionally sharded across devices: the stacked batch
-is padded to a multiple of the device count
-(:func:`repro.distributed.sharding.pad_batch`), placed with a
+is padded to a multiple of the device count, and to two lanes per device
+at least (:func:`repro.distributed.sharding.pad_batch`), placed with a
 ``batch``-sharded layout, and run through a ``shard_map``-wrapped runner
 whose input buffers are donated.  Each device early-exits on its own shard
 independently.
@@ -149,17 +149,18 @@ def _prepare(sc: Scenario) -> _Prepared:
                      total_s=sc.total_s, dt=sc.dt)
 
 
-def _postprocess(sim, metrics, prep: _Prepared) -> TransferResult:
-    m = jax.tree.map(np.asarray, metrics)
+def _postprocess(sim, done_at, prep: _Prepared) -> TransferResult:
+    """One lane's result from its final state and completion tick; the
+    result carries no traces (``run`` attaches its own)."""
     sim = jax.tree.map(np.asarray, sim)
-    # Completion comes from the final state, not the trace: the early-exit
-    # runner leaves never-executed tail ticks at their done=True buffer init.
+    # Completion comes from the final state; ``done_at`` (-1 while live)
+    # only dates it.
     completed = bool(np.sum(sim.remaining_mb) <= 0.0)
     if completed:
-        # ``done[i]`` is recorded post-step: the transfer drained DURING tick
-        # i, i.e. at time (i + 1) * dt.  (A transfer finishing on tick 0 took
-        # one dt, not zero seconds.)
-        t_done = float(prep.dt * (int(np.argmax(m.done)) + 1))
+        # The transfer drained DURING tick ``done_at``, i.e. at time
+        # (done_at + 1) * dt.  (A transfer finishing on tick 0 took one dt,
+        # not zero seconds.)
+        t_done = float(prep.dt * (int(done_at) + 1))
     else:
         t_done = float(prep.total_s)
     energy = float(sim.energy_j)
@@ -174,7 +175,6 @@ def _postprocess(sim, metrics, prep: _Prepared) -> TransferResult:
         avg_tput_gbps=avg_tput * 8.0 / 1000.0,
         avg_power_w=avg_power,
         completed=completed,
-        metrics=m,
     )
 
 
@@ -229,25 +229,30 @@ def _merged_partition_counts(keys) -> dict:
 
 
 def _fetch(out, batch: Optional[int] = None):
-    """Wait for a runner's ``(sim, ts, metrics)``, then copy ``sim`` and
-    the per-tick metrics to the host, cut to ``batch`` lanes if given."""
+    """Wait for a runner's ``(sim, ts, tail)``, then copy ``sim`` and
+    ``tail`` (the completion ticks, or ``api.run``'s per-tick metrics) to
+    the host, cut to ``batch`` lanes if given."""
     with obs.span("sweep.wait"):
         jax.block_until_ready(out)
-    sim, _, metrics = out
+    sim, _, tail = out
+    kept = (sim, tail)
     if batch is None:
-        host = np.asarray
-        n_bytes = sum(x.nbytes for x in jax.tree.leaves(metrics))
+        n_bytes = sum(x.nbytes for x in jax.tree.leaves(kept))
     else:
-        def host(x):
-            return np.asarray(x)[:batch]
         n_bytes = sum(x.nbytes // x.shape[0] * batch
-                      for x in jax.tree.leaves(metrics))
+                      for x in jax.tree.leaves(kept))
     with obs.span("sweep.fetch", bytes=n_bytes):
-        return jax.tree.map(host, sim), jax.tree.map(host, metrics)
+        # ``device_get`` starts every leaf's copy before it waits on any.
+        host = jax.device_get(kept)
+        if batch is None:
+            return host
+        return jax.tree.map(lambda x: x[:batch], host)
 
 
-def _run_prepared(prep: _Prepared) -> TransferResult:
-    """Execute one prepared scenario on the unbatched cached runner."""
+def run(scenario: Scenario) -> TransferResult:
+    """Run one scenario to completion (or its ``total_s`` timeout) on the
+    unbatched cached runner; the result holds its per-tick traces."""
+    prep = _prepare(scenario)
     k = prep.key
     with obs.span("sweep.launch"):
         runner = engine.get_runner(k.ctrl_code, k.env_code, k.cpu,
@@ -256,24 +261,30 @@ def _run_prepared(prep: _Prepared) -> TransferResult:
         out = runner(prep.inputs)
     sim, metrics = _fetch(out)
     with obs.span("sweep.postprocess"):
-        return _postprocess(sim, metrics, prep)
-
-
-def run(scenario: Scenario) -> TransferResult:
-    """Run one scenario to completion (or its ``total_s`` timeout)."""
-    return _run_prepared(_prepare(scenario))
+        # ``done[i]`` is recorded post-step, so its first True is the
+        # completion tick the trace-free runners carry.
+        r = _postprocess(sim, np.argmax(metrics.done), prep)
+        return dataclasses.replace(r, metrics=metrics)
 
 
 def _run_group(key: _GroupKey, stacked, batch: int, devices):
-    """Execute one stacked group, sharding across devices when possible.
+    """Execute one stacked group on a trace-free runner, sharding across
+    devices when possible.
 
-    Returns (sim, metrics) pytrees with numpy leaves and a leading batch
-    axis of exactly ``batch`` (device padding stripped).
+    Returns ``(sim, done_at)``: the final state's pytree and the completion
+    ticks, numpy leaves with a leading batch axis of exactly ``batch``
+    (device padding stripped).
     """
     from repro.distributed import sharding as shd
     with obs.span("sweep.launch"):
-        if shd.should_shard(batch, devices):
-            stacked, _ = shd.pad_batch(stacked, len(devices))
+        shards = len(devices) if shd.should_shard(batch, devices) else 1
+        # Every program runs two lanes or more on each device.  XLA folds a
+        # batch axis of one away, and the program it then compiles may round
+        # a multiply-add twice where the wider programs fuse it into one
+        # FMA, so a lane's bits would depend on its group's size.
+        stacked, _ = shd.pad_batch(
+            stacked, shards * (2 if batch < 2 * shards else 1))
+        if shards > 1:
             mesh = shd.batch_mesh(devices)
             runner = engine.get_sharded_runner(
                 key.ctrl_code, key.env_code, key.cpu, key.n_steps, key.dt,
@@ -282,7 +293,8 @@ def _run_group(key: _GroupKey, stacked, batch: int, devices):
         else:
             runner = engine.get_runner(key.ctrl_code, key.env_code, key.cpu,
                                        key.n_steps, key.dt, key.ctrl_every,
-                                       batched=True, executor=key.executor)
+                                       batched=True, traces=False,
+                                       executor=key.executor)
             out = runner(stacked)
     return _fetch(out, batch)
 
@@ -292,9 +304,12 @@ def sweep(scenarios: Sequence[Scenario], *,
     """Run many scenarios, batching shape-compatible ones into one launch.
 
     Results come back in input order.  Scenarios group when their compiled
-    code is identical; each group of size > 1 executes as one vmapped call
-    of the early-exiting engine, singletons fall back to the unbatched
-    runner (which shares the per-group cache with :func:`run`).
+    code is identical; each group executes as one vmapped call of the
+    early-exiting engine, of two lanes or more (see ``_run_group``).
+    The runners are trace-free: each carries its lanes' completion ticks
+    instead of per-tick traces, so only the final state and one int32 per
+    lane come back to the host, and results hold no ``metrics`` (``run``
+    keeps them).  A lane's result does not depend on its group's size.
 
     ``devices`` selects the devices groups shard across (default: all local
     devices).  With more than one device, each group with at least one lane
@@ -334,21 +349,17 @@ def sweep(scenarios: Sequence[Scenario], *,
             with obs.span("sweep.group", lanes=len(idxs),
                           n_steps=key.n_steps,
                           partitions=key.n_partitions):
-                if len(idxs) == 1:
-                    results[idxs[0]] = _run_prepared(prepared[idxs[0]])
-                    continue
                 with obs.span("sweep.launch"):
                     stacked = jax.tree.map(
                         lambda *xs: np.stack(xs),
                         *[prepared[i].inputs for i in idxs])
-                sim_np, metrics_np = _run_group(key, stacked, len(idxs),
-                                                devices)
+                sim_np, done_np = _run_group(key, stacked, len(idxs),
+                                             devices)
                 with obs.span("sweep.postprocess"):
                     for b, i in enumerate(idxs):
                         results[i] = _postprocess(
                             jax.tree.map(lambda x: x[b], sim_np),
-                            jax.tree.map(lambda x: x[b], metrics_np),
-                            prepared[i])
+                            done_np[b], prepared[i])
     return results
 
 

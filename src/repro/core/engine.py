@@ -32,7 +32,9 @@ is only *simulated* until it drains:
 * **Done semantics.**  ``TickMetrics.done[i]`` is recorded *after* step
   ``i``: it is True from the tick during which the transfer drained.  The
   completion time is therefore ``(argmax(done) + 1) * dt``, and ``SimState.t``
-  freezes at exactly that value.
+  freezes at exactly that value.  Cores built with ``traces=False`` (what
+  ``repro.api.sweep`` runs) emit no per-tick metrics: they carry that index
+  as ``done_at`` (int32, -1 while the transfer is live) through the loop.
 
 The lowering contract (flat state + executors)
 ----------------------------------------------
@@ -150,7 +152,9 @@ class TransferResult:
     avg_tput_gbps: float          # Gbit/s (paper's unit)
     avg_power_w: float
     completed: bool
-    metrics: TickMetrics          # per-tick traces (numpy)
+    # Per-tick traces (numpy) from ``api.run``; ``None`` from ``api.sweep``,
+    # whose runners carry only the completion tick.
+    metrics: Optional[TickMetrics] = None
 
     @property
     def avg_tput_mbps(self) -> float:
@@ -382,6 +386,29 @@ def _init_obs_buffer(padded: int) -> Observation:
     )
 
 
+def _done_at(done, step0=0):
+    """Completion tick of a ``[steps]`` done trace: ``step0`` plus the first
+    tick after which the transfer was drained, or -1 if it never was."""
+    return jnp.where(done[-1], step0 + jnp.argmax(done).astype(jnp.int32),
+                     jnp.asarray(-1, jnp.int32))
+
+
+def _track_completion(step):
+    """Wrap a scan step to emit nothing and carry ``done_at`` beside the
+    state: the first step index after which ``partition_sum(remaining_mb)
+    <= 0``, where ``TickMetrics.done`` first turns True."""
+
+    def tracked(carry, xs):
+        state, done_at = carry
+        state, _ = step(state, xs)
+        drained = partition_sum(state[0].remaining_mb) <= 0.0
+        done_at = jnp.where(jnp.logical_and(done_at < 0, drained), xs[0],
+                            done_at)
+        return (state, done_at), None
+
+    return tracked
+
+
 def _chunking(n_steps: int, chunk: Optional[int]):
     if chunk is None:
         chunk = max(MIN_CHUNK, -(-n_steps // MAX_CHUNKS))
@@ -393,15 +420,21 @@ def _chunking(n_steps: int, chunk: Optional[int]):
 def build_core(controller, env, cpu: CpuProfile, *, n_steps: int, dt: float,
                ctrl_every: int, early_exit: bool = True,
                chunk: Optional[int] = None, observe: bool = False,
-               executor: str = "reference"):
+               traces: bool = True, executor: str = "reference"):
     """One full transfer: ScanInputs -> (final SimState, TunerState, traces).
 
     Pure and shape-stable in its pytree argument, hence vmap-able across a
     batch of scenarios.  With ``early_exit`` (the default) the horizon is
     split into ``chunk``-tick scans inside a ``lax.while_loop`` that stops
-    once every lane of the batch is done; metrics land in a preallocated
-    [n_steps] buffer via ``dynamic_update_slice`` so the output shape is
+    once every lane of the batch is done; each chunk's metrics land in a
+    preallocated [n_steps] buffer (``engine.store``) so the output shape is
     identical to the reference full-horizon scan (``early_exit=False``).
+
+    With ``traces=False`` the third output is ``done_at`` instead of the
+    traces: the int32 index of the tick during which the transfer drained
+    (``argmax`` of the ``done`` trace), or -1 if it did not.  The early-exit
+    loop carries it and its chunk scans emit nothing, so no [n_steps]
+    buffer exists; ``observe`` needs the traces.
 
     ``executor`` selects the lowering (see the module docstring):
     ``reference`` scans the pytree carry, ``blocked`` carries the flat
@@ -415,9 +448,19 @@ def build_core(controller, env, cpu: CpuProfile, *, n_steps: int, dt: float,
     the classic ``(sim, ts, metrics)`` triple (and an unchanged program).
     """
     executor = resolve_executor(executor, observe=observe)
+    if observe and not traces:
+        raise ValueError("observe=True emits per-tick traces; it needs "
+                         "traces=True")
     if executor == "pallas":
-        return _build_pallas_core(controller, env, cpu, n_steps=n_steps,
-                                  dt=dt, ctrl_every=ctrl_every)
+        pallas = _build_pallas_core(controller, env, cpu, n_steps=n_steps,
+                                    dt=dt, ctrl_every=ctrl_every)
+        if traces:
+            return pallas
+
+        def trace_free(inp: ScanInputs):
+            sim, ts, m = pallas(inp)
+            return sim, ts, _done_at(m.done)
+        return trace_free
     chunk, n_chunks, padded = _chunking(n_steps, chunk)
     blocked = executor == "blocked"
 
@@ -446,20 +489,44 @@ def build_core(controller, env, cpu: CpuProfile, *, n_steps: int, dt: float,
                 (sim, ts), ys = jax.lax.scan(step, (sim0, inp.state0), xs)
             if observe:
                 return sim, ts, ys[0], ys[1]
-            return sim, ts, ys
+            return sim, ts, ys if traces else _done_at(ys.done)
 
         bw = jnp.pad(inp.bw, ((0, padded - n_steps),))
 
-        @jax.named_scope("engine.store")
-        def store(buf, m, start):
-            return jax.tree.map(
-                lambda b, x: jax.lax.dynamic_update_slice(
-                    b, x, (start,) + (0,) * (b.ndim - 1)),
-                buf, m)
+        def chunk_xs(start):
+            return (start + jnp.arange(chunk, dtype=jnp.int32),
+                    jax.lax.dynamic_slice(bw, (start,), (chunk,)))
 
-        buf0 = _init_metrics_buffer(padded)
-        if observe:
-            buf0 = (buf0, _init_obs_buffer(padded))
+        # ``advance`` runs the chunk that begins at tick ``start``.  ``out``
+        # is what the while loop carries beside the state: the [padded]
+        # trace buffers, or the completion tick.
+        if traces:
+            @jax.named_scope("engine.store")
+            def store(buf, m, start):
+                return jax.tree.map(
+                    lambda b, x: jax.lax.dynamic_update_slice(
+                        b, x, (start,) + (0,) * (b.ndim - 1)),
+                    buf, m)
+
+            def advance(state, buf, start):
+                state, m = jax.lax.scan(step, state, chunk_xs(start))
+                return state, store(buf, m, start)
+
+            out0 = _init_metrics_buffer(padded)
+            if observe:
+                out0 = (out0, _init_obs_buffer(padded))
+        else:
+            tracked = _track_completion(step)
+
+            def advance(state, done_at, start):
+                (state, done_at), _ = jax.lax.scan(
+                    tracked, (state, done_at), chunk_xs(start))
+                return state, done_at
+
+            # A lane born drained reads 0, as its trace does: the loop may
+            # never run, and the buffer's never-executed ticks are done.
+            out0 = jnp.where(partition_sum(sim0.remaining_mb) <= 0.0,
+                             0, -1).astype(jnp.int32)
 
         if blocked:
             # Flat TickState rows cross the while-loop boundary; the pytree
@@ -472,18 +539,14 @@ def build_core(controller, env, cpu: CpuProfile, *, n_steps: int, dt: float,
 
             @jax.named_scope("engine.chunk")
             def body(carry):
-                k, f32, i32, buf = carry
-                start = k * chunk
-                idx = start + jnp.arange(chunk, dtype=jnp.int32)
-                bw_chunk = jax.lax.dynamic_slice(bw, (start,), (chunk,))
-                st, m = jax.lax.scan(step, lay.unpack_state(f32, i32),
-                                     (idx, bw_chunk))
+                k, f32, i32, out = carry
+                st, out = advance(lay.unpack_state(f32, i32), out, k * chunk)
                 f32, i32 = lay.pack_state(*st)
-                return k + 1, f32, i32, store(buf, m, start)
+                return k + 1, f32, i32, out
 
             f0, i0 = lay.pack_state(sim0, inp.state0)
-            carry0 = (jnp.zeros((), jnp.int32), f0, i0, buf0)
-            _, f32, i32, buf = jax.lax.while_loop(cond, body, carry0)
+            carry0 = (jnp.zeros((), jnp.int32), f0, i0, out0)
+            _, f32, i32, out = jax.lax.while_loop(cond, body, carry0)
             sim, ts = lay.unpack_state(f32, i32)
         else:
             def cond(carry):
@@ -493,17 +556,16 @@ def build_core(controller, env, cpu: CpuProfile, *, n_steps: int, dt: float,
 
             @jax.named_scope("engine.chunk")
             def body(carry):
-                k, state, buf = carry
-                start = k * chunk
-                idx = start + jnp.arange(chunk, dtype=jnp.int32)
-                bw_chunk = jax.lax.dynamic_slice(bw, (start,), (chunk,))
-                state, m = jax.lax.scan(step, state, (idx, bw_chunk))
-                return k + 1, state, store(buf, m, start)
+                k, state, out = carry
+                state, out = advance(state, out, k * chunk)
+                return k + 1, state, out
 
-            carry0 = (jnp.zeros((), jnp.int32), (sim0, inp.state0), buf0)
-            _, (sim, ts), buf = jax.lax.while_loop(cond, body, carry0)
+            carry0 = (jnp.zeros((), jnp.int32), (sim0, inp.state0), out0)
+            _, (sim, ts), out = jax.lax.while_loop(cond, body, carry0)
 
-        out = jax.tree.map(lambda b: b[:n_steps], buf)
+        if not traces:
+            return sim, ts, out
+        out = jax.tree.map(lambda b: b[:n_steps], out)
         if observe:
             return sim, ts, out[0], out[1]
         return sim, ts, out
@@ -663,7 +725,8 @@ def _cached(family: str, key: tuple, build):
 def get_runner(controller_code, env_code, cpu: CpuProfile, n_steps: int,
                dt: float, ctrl_every: int, batched: bool,
                early_exit: bool = True, chunk: Optional[int] = None,
-               observe: bool = False, executor: str = "auto"):
+               observe: bool = False, traces: bool = True,
+               executor: str = "auto"):
     """Jitted (and optionally vmapped) engine core, cached per code group.
 
     ``controller_code`` must be a canonical (numerics-stripped, hashable)
@@ -674,17 +737,19 @@ def get_runner(controller_code, env_code, cpu: CpuProfile, n_steps: int,
     shape-compatible, so lanes tend to finish at similar times).
 
     ``executor`` is resolved first (:func:`resolve_executor`), so
-    ``"auto"`` and ``"blocked"`` share one cache entry.
+    ``"auto"`` and ``"blocked"`` share one cache entry.  ``traces`` picks
+    the core's form (:func:`build_core`): ``api.run`` keeps the per-tick
+    traces, ``api.sweep`` takes the trace-free form.
     """
     executor = resolve_executor(executor, observe=observe)
     key = (controller_code, env_code, cpu, n_steps, dt, ctrl_every,
-           batched, early_exit, chunk, observe, executor)
+           batched, early_exit, chunk, observe, traces, executor)
 
     def build():
         core = build_core(controller_code, env_code, cpu, n_steps=n_steps,
                           dt=dt, ctrl_every=ctrl_every,
                           early_exit=early_exit, chunk=chunk,
-                          observe=observe, executor=executor)
+                          observe=observe, traces=traces, executor=executor)
         return jax.jit(jax.vmap(core) if batched else core)
 
     return _cached("runner", key, build)
@@ -747,10 +812,7 @@ def build_wave_core(controller, env, cpu: CpuProfile, *, wave_steps: int,
         bw = jnp.broadcast_to(jnp.asarray(inp.bw, jnp.float32),
                               (wave_steps,))
         (sim, ts), done = jax.lax.scan(wave_step, (sim0, ts0), (idx, bw))
-        done_at = jnp.where(done[-1],
-                            step0 + jnp.argmax(done).astype(jnp.int32),
-                            jnp.asarray(-1, jnp.int32))
-        return sim, ts, done_at
+        return sim, ts, _done_at(done, step0)
 
     return core
 
@@ -785,11 +847,8 @@ def build_blocked_wave_core(controller, env, cpu: CpuProfile, *,
         idx = step0 + jnp.arange(wave_steps, dtype=jnp.int32)
         bws = jnp.broadcast_to(jnp.asarray(bw, jnp.float32), (wave_steps,))
         (sim, ts), done = jax.lax.scan(wave_step, (sim0, ts0), (idx, bws))
-        done_at = jnp.where(done[-1],
-                            step0 + jnp.argmax(done).astype(jnp.int32),
-                            jnp.asarray(-1, jnp.int32))
         f32_out, i32_out = lay.pack_state(sim, ts)
-        return f32_out, i32_out, done_at
+        return f32_out, i32_out, _done_at(done, step0)
 
     return core
 
@@ -908,7 +967,9 @@ def get_sharded_runner(controller_code, env_code, cpu: CpuProfile,
                        devices: tuple, early_exit: bool = True,
                        chunk: Optional[int] = None,
                        executor: str = "auto"):
-    """Batched engine core sharded over ``devices`` along the batch axis.
+    """Batched trace-free engine core (``build_core(traces=False)``: it
+    returns ``(sim, ts, done_at)``) sharded over ``devices`` along the batch
+    axis.
 
     Built with ``shard_map`` over a 1-D ``batch`` mesh, so each device runs
     the early-exit loop on its own shard independently — a device whose
@@ -931,7 +992,7 @@ def get_sharded_runner(controller_code, env_code, cpu: CpuProfile,
         core = build_core(controller_code, env_code, cpu, n_steps=n_steps,
                           dt=dt, ctrl_every=ctrl_every,
                           early_exit=early_exit, chunk=chunk,
-                          executor=executor)
+                          traces=False, executor=executor)
         f = jax.shard_map(jax.vmap(core), mesh=mesh, in_specs=(P("batch"),),
                           out_specs=P("batch"), check_vma=False)
         return jax.jit(f, donate_argnums=0)

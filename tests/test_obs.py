@@ -10,9 +10,11 @@ import os
 from collections import Counter
 
 import jax
+import numpy as np
 import pytest
 
 from repro import api, obs
+from repro.api import scenario as _scenario
 from repro.core import engine
 from repro.core.types import CHAMELEON, DatasetSpec
 
@@ -46,19 +48,30 @@ def same_bits(a, b) -> bool:
 
 @pytest.fixture(scope="module")
 def swept(tmp_path_factory):
-    """One sweep with the profiler off, then the same sweep traced."""
+    """One sweep with the profiler off, then the same sweep traced, with
+    what each of its ``_fetch`` calls returned."""
     scs = scenarios()
     assert api.group_count(scs) == 3
     obs.clear()
     off = api.sweep(scs)
     kept_off = obs.spans()
     log_dir = str(tmp_path_factory.mktemp("trace"))
-    with jax.profiler.trace(log_dir):
-        on = api.sweep(scs)
+    fetch, fetched = _scenario._fetch, []
+
+    def spy_fetch(*args):
+        fetched.append(fetch(*args))
+        return fetched[-1]
+
+    _scenario._fetch = spy_fetch
+    try:
+        with jax.profiler.trace(log_dir):
+            on = api.sweep(scs)
+    finally:
+        _scenario._fetch = fetch
     recs = obs.spans()
     obs.clear()
     return {"off": off, "on": on, "kept_off": kept_off, "records": recs,
-            "log_dir": log_dir}
+            "log_dir": log_dir, "fetched": fetched}
 
 
 def test_no_record_without_profiler(swept):
@@ -97,16 +110,22 @@ def test_spans_nest_under_one_trace(swept):
 def test_leaves_do_not_overlap(swept):
     leaves = sorted((r.start_ns, r.end_ns) for r in swept["records"]
                     if r.name in LEAVES)
-    assert len(leaves) == 1 + 2 * 5 + 4   # prepare; 2 groups; singleton
+    assert len(leaves) == 1 + 3 * 5       # prepare; 2 groups; singleton
     for (_, end), (start, _) in zip(leaves, leaves[1:]):
         assert end <= start
 
 
 def test_fetch_bytes_are_the_results_metrics(swept):
-    fetched = sum(r.meta["bytes"] for r in swept["records"]
-                  if r.name == "sweep.fetch")
-    assert fetched == sum(leaf.nbytes for r in swept["on"]
-                          for leaf in jax.tree.leaves(r.metrics))
+    """``sweep.fetch``'s bytes are what ``_fetch`` copies: each lane's
+    final state and completion tick, and no per-tick metrics."""
+    fetched = [r.meta["bytes"] for r in swept["records"]
+               if r.name == "sweep.fetch"]
+    copied = [sum(leaf.nbytes for leaf in jax.tree.leaves(out))
+              for out in swept["fetched"]]
+    assert fetched == copied
+    assert all(r.metrics is None for r in swept["on"])
+    for (_, done_at), lanes in zip(swept["fetched"], (3, 2, 1)):
+        assert done_at.dtype == np.int32 and done_at.size == lanes
 
 
 def test_spans_on_the_profilers_host_plane(swept):

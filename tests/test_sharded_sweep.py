@@ -27,8 +27,9 @@ assert jax.device_count() == 4, jax.devices()
 
 import numpy as np
 from repro import api
+from repro.api import scenario as _scenario
 from repro.core import engine
-from repro.core.types import CHAMELEON, DatasetSpec
+from repro.core.types import CHAMELEON, CLOUDLAB, DatasetSpec
 
 FAST = (DatasetSpec("a", 200, 400.0, 2.0),
         DatasetSpec("b", 10, 600.0, 60.0))
@@ -41,25 +42,44 @@ def group(max_chs):
             for mc in max_chs]
 
 
-# 6 lanes in one group -> padded to 8 across 4 devices; 4 lanes -> one
-# per device; 3 lanes -> fewer lanes than devices, so unsharded.
+# The completion tick each swept lane's result is made from, in order.
+ticks = []
+post = _scenario._postprocess
+
+
+def spy_post(sim, done_at, prep):
+    ticks.append(int(done_at))
+    return post(sim, done_at, prep)
+
+
+_scenario._postprocess = spy_post
+
+# A transfer whose energy sum XLA's CPU backend compiles with one FMA
+# fewer where a device runs a single lane.
+wget = [api.Scenario(profile=CLOUDLAB, datasets=FAST, controller="wget/curl",
+                     total_s=120.0, name=f"w{i}") for i in range(4)]
+
+# 6 lanes in one group -> padded to 8 across 4 devices; 4 lanes -> padded
+# to two per device; 3 lanes -> fewer lanes than devices, so unsharded.
 for scenarios, sharded in ((group((4, 8, 16, 32, 64, 48)), 1),
-                           (group((4, 8, 16, 32)), 1),
+                           (group((4, 8, 16, 32)), 1), (wget, 1),
                            (group((4, 8, 16)), 0)):
     assert api.group_count(scenarios) == 1
     engine.clear_runner_caches()
+    ticks.clear()
     swept = api.sweep(scenarios)
     assert engine.runner_cache_sizes()["sharded"] == sharded
     assert len(swept) == len(scenarios)
-    for sc, batched in zip(scenarios, swept):
+    assert len(ticks) == len(scenarios)
+    for sc, batched, tick in zip(scenarios, swept, list(ticks)):
         single = api.run(sc)             # unbatched, single-device path
         assert single.completed == batched.completed
         assert single.time_s == batched.time_s, (single.time_s,
                                                  batched.time_s)
         assert single.energy_j == batched.energy_j
         assert single.avg_tput_MBps == batched.avg_tput_MBps
-        assert (batched.metrics.tput_mbps.shape
-                == single.metrics.tput_mbps.shape)
+        assert single.completed and batched.metrics is None
+        assert tick == int(np.argmax(single.metrics.done)), tick
 print("SHARDED-SWEEP-OK")
 """
 

@@ -99,12 +99,13 @@ def _widest_group(which):
 
 @pytest.mark.parametrize("which", ["widest", "tuned"])
 def test_sweep_runner_compiles_at_fig2_size(one_chip, which):
-    """The vmapped blocked sweep runner at the grid's widest partition
-    count and full horizon."""
+    """The vmapped blocked sweep runner, trace-free as ``sweep`` runs it,
+    at the grid's widest partition count and full horizon."""
     key, stacked = _widest_group(which)
     runner = engine.get_runner(key.ctrl_code, key.env_code, key.cpu,
                                key.n_steps, key.dt, key.ctrl_every,
-                               batched=True, executor="blocked")
+                               batched=True, traces=False,
+                               executor="blocked")
     compiled = runner.lower(_shapes(stacked, one_chip)).compile()
     _fits(compiled)
 

@@ -36,7 +36,7 @@ def bench_engine(rows=None):
     ctrl = api.make_controller("eemt", max_ch=64)
     ci = ctrl.init(MIXED, CHAMELEON, CPU)
     inp = jax.tree.map(np.asarray,
-                       engine.ScanInputs.from_init(ci, CHAMELEON, n_steps))
+                       engine.ScanInputs.from_init(ci, CHAMELEON))
     runner = engine.get_runner(ctrl.code(), api.as_environment(None).code(),
                                CPU, n_steps, 0.1, 10,
                                batched=False, early_exit=False)
@@ -68,7 +68,7 @@ def bench_engine_executors(bench=None, n_steps=6000):
     ctrl = api.make_controller("eemt", max_ch=64)
     ci = ctrl.init(MIXED, CHAMELEON, CPU)
     inp = jax.tree.map(np.asarray,
-                       engine.ScanInputs.from_init(ci, CHAMELEON, n_steps))
+                       engine.ScanInputs.from_init(ci, CHAMELEON))
     inp = inp._replace(total_mb=inp.total_mb * 1e6)   # never completes
     env = api.as_environment(None).code()
     for ex in ("reference", "blocked", "pallas"):
@@ -96,7 +96,7 @@ def bench_vmap_sweep(rows=None):
     n_steps = 2000
     ctrl = api.make_controller("eemt", max_ch=64)
     ci = ctrl.init(MIXED, CHAMELEON, CPU)
-    base = engine.ScanInputs.from_init(ci, CHAMELEON, n_steps)
+    base = engine.ScanInputs.from_init(ci, CHAMELEON)
     # Full-horizon reference: every lane really executes n_steps ticks, so
     # the sim_steps_per_s metric divides by the work actually done.
     core = engine.build_core(ctrl.code(), api.as_environment(None).code(),

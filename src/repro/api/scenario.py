@@ -57,6 +57,11 @@ class Scenario:
     knob.  It joins the sweep group key, so mixing executors in one sweep simply
     splits groups.
 
+    ``bw_schedule`` is ``None``, the full path rate (the engine's input
+    ``ScanInputs.bw`` is then ``[]``, one share every tick sees), or an
+    ``[n_steps]`` share of the path's bandwidth per tick.  A scheduled
+    scenario and an unscheduled one never share an executable.
+
     ``eq=False``: scenarios may carry an ndarray ``bw_schedule``, so equality
     and hashing are by identity (array fields would make ``==`` ambiguous).
     """
@@ -68,7 +73,7 @@ class Scenario:
     environment: Optional[Any] = None   # None -> reference physics
     total_s: float = 3600.0
     dt: float = 0.1
-    bw_schedule: Optional[Any] = None   # [n_steps] fraction of bandwidth
+    bw_schedule: Optional[Any] = None   # None (full rate) or [n_steps] share
     name: Optional[str] = None
     executor: str = "auto"              # engine lowering (see repro.core)
 
@@ -99,6 +104,7 @@ class _GroupKey(NamedTuple):
     ctrl_every: int
     n_partitions: int
     executor: str
+    scheduled: bool     # ``bw`` is an [n_steps] schedule, not a [] share
 
 
 def ctrl_stride(ctrl: Controller, dt: float) -> int:
@@ -119,7 +125,8 @@ def _group_key(ctrl: Controller, env: Environment, sc: Scenario,
     # executable) with one that named the same executor explicitly.
     return _GroupKey(ctrl.code(), env.code(), sc.cpu, n_steps, sc.dt,
                      ctrl_stride(ctrl, sc.dt), n_partitions,
-                     engine.resolve_executor(sc.executor))
+                     engine.resolve_executor(sc.executor),
+                     sc.bw_schedule is not None)
 
 
 class _Prepared(NamedTuple):
@@ -135,13 +142,13 @@ def _prepare(sc: Scenario) -> _Prepared:
     env = as_environment(sc.environment)
     ci = ctrl.init(sc.datasets, sc.profile, sc.cpu)
     key = _group_key(ctrl, env, sc, len(ci.specs))
-    n_steps = key.n_steps
 
-    inputs = ScanInputs.from_init(ci, sc.profile, n_steps)
-    if sc.bw_schedule is not None:
+    inputs = ScanInputs.from_init(ci, sc.profile)
+    if key.scheduled:
         bw = np.asarray(sc.bw_schedule, np.float32)
-        if bw.shape != (n_steps,):
-            raise ValueError(f"bw_schedule shape {bw.shape} != ({n_steps},)")
+        if bw.shape != (key.n_steps,):
+            raise ValueError(
+                f"bw_schedule shape {bw.shape} != ({key.n_steps},)")
         inputs = inputs._replace(bw=bw)
     inputs = jax.tree.map(np.asarray, inputs)
     return _Prepared(key=key, inputs=inputs,
@@ -237,7 +244,7 @@ def _fetch(out, batch: Optional[int] = None):
     sim, _, tail = out
     kept = (sim, tail)
     if batch is None:
-        n_bytes = sum(x.nbytes for x in jax.tree.leaves(kept))
+        n_bytes = _nbytes(kept)
     else:
         n_bytes = sum(x.nbytes // x.shape[0] * batch
                       for x in jax.tree.leaves(kept))
@@ -249,12 +256,17 @@ def _fetch(out, batch: Optional[int] = None):
         return jax.tree.map(lambda x: x[:batch], host)
 
 
+def _nbytes(tree) -> int:
+    """Bytes of a pytree's array leaves (host or device)."""
+    return sum(x.nbytes for x in jax.tree.leaves(tree))
+
+
 def run(scenario: Scenario) -> TransferResult:
     """Run one scenario to completion (or its ``total_s`` timeout) on the
     unbatched cached runner; the result holds its per-tick traces."""
     prep = _prepare(scenario)
     k = prep.key
-    with obs.span("sweep.launch"):
+    with obs.span("sweep.launch", bytes=_nbytes(prep.inputs)):
         runner = engine.get_runner(k.ctrl_code, k.env_code, k.cpu,
                                    k.n_steps, k.dt, k.ctrl_every,
                                    batched=False, executor=k.executor)
@@ -276,7 +288,7 @@ def _run_group(key: _GroupKey, stacked, batch: int, devices):
     (device padding stripped).
     """
     from repro.distributed import sharding as shd
-    with obs.span("sweep.launch"):
+    with obs.span("sweep.launch") as launch:
         shards = len(devices) if shd.should_shard(batch, devices) else 1
         # Every program runs two lanes or more on each device.  XLA folds a
         # batch axis of one away, and the program it then compiles may round
@@ -284,6 +296,7 @@ def _run_group(key: _GroupKey, stacked, batch: int, devices):
         # FMA, so a lane's bits would depend on its group's size.
         stacked, _ = shd.pad_batch(
             stacked, shards * (2 if batch < 2 * shards else 1))
+        launch.annotate(bytes=_nbytes(stacked))
         if shards > 1:
             mesh = shd.batch_mesh(devices)
             runner = engine.get_sharded_runner(

@@ -178,12 +178,14 @@ class ScanInputs(NamedTuple):
     avg_file_mb: jnp.ndarray   # [P] average file (or chunk) size
     state0: TunerState     # initial controller state (numCh, cores, freq, ..)
     static_w: jnp.ndarray  # [P] frozen channel weights (controller-specific)
-    bw: jnp.ndarray        # [n_steps] available-bandwidth schedule
+    bw: jnp.ndarray        # [] or [n_steps] share of the path's bandwidth
 
     @classmethod
-    def from_init(cls, ci, profile, n_steps: int) -> "ScanInputs":
-        """Assemble inputs from a ``ControllerInit`` + profile, with a flat
-        bandwidth schedule (override ``bw`` via ``_replace`` if needed).
+    def from_init(cls, ci, profile) -> "ScanInputs":
+        """Assemble inputs from a ``ControllerInit`` + profile, with the
+        full path rate: ``bw`` is one float32 1.0 that every tick sees
+        (``_replace`` it with an ``[n_steps]`` schedule where one is given;
+        :func:`tick_bw` reads either).
 
         Leaves built here are host-side (numpy) so batch stacking stays on
         the host; ``pp``/``par``/``state0`` pass through as the controller
@@ -201,7 +203,7 @@ class ScanInputs(NamedTuple):
                                    np.float32),
             state0=ci.state,
             static_w=np.asarray(ci.static_weights, np.float32),
-            bw=np.ones((n_steps,), np.float32),
+            bw=np.float32(1.0),
         )
 
 
@@ -409,6 +411,25 @@ def _track_completion(step):
     return tracked
 
 
+def tick_bw(bw, horizon: int):
+    """One lane's per-tick bandwidth: ``window(start, length)`` gives the
+    shares of ticks ``[start, start + length)``.
+
+    ``bw`` is a rank-0 share that every tick sees (a scenario without a
+    schedule, a fleet lane's share for one wave) or an ``[n_steps]``
+    schedule, padded with zeros to ``horizon`` ticks so that every window
+    lies inside it.  Under ``vmap`` the lane's rank is known at trace
+    time, so each rank compiles its own program; the tick sees the same
+    float32 values either way.
+    """
+    bw = jnp.asarray(bw, jnp.float32)
+    if bw.ndim == 0:
+        return lambda start, length: jnp.broadcast_to(bw, (length,))
+    bw = jnp.pad(bw, ((0, horizon - bw.shape[0]),))
+    return lambda start, length: jax.lax.dynamic_slice(bw, (start,),
+                                                       (length,))
+
+
 def _chunking(n_steps: int, chunk: Optional[int]):
     if chunk is None:
         chunk = max(MIN_CHUNK, -(-n_steps // MAX_CHUNKS))
@@ -475,7 +496,8 @@ def build_core(controller, env, cpu: CpuProfile, *, n_steps: int, dt: float,
                             observe=observe)
 
         if not early_exit:
-            xs = (jnp.arange(n_steps, dtype=jnp.int32), inp.bw)
+            xs = (jnp.arange(n_steps, dtype=jnp.int32),
+                  tick_bw(inp.bw, n_steps)(0, n_steps))
             if blocked:
                 carry0 = lay.pack_state(sim0, inp.state0)
 
@@ -491,11 +513,11 @@ def build_core(controller, env, cpu: CpuProfile, *, n_steps: int, dt: float,
                 return sim, ts, ys[0], ys[1]
             return sim, ts, ys if traces else _done_at(ys.done)
 
-        bw = jnp.pad(inp.bw, ((0, padded - n_steps),))
+        bw = tick_bw(inp.bw, padded)
 
         def chunk_xs(start):
             return (start + jnp.arange(chunk, dtype=jnp.int32),
-                    jax.lax.dynamic_slice(bw, (start,), (chunk,)))
+                    bw(start, chunk))
 
         # ``advance`` runs the chunk that begins at tick ``start``.  ``out``
         # is what the while loop carries beside the state: the [padded]
@@ -597,7 +619,7 @@ def _build_pallas_core(controller, env, cpu: CpuProfile, *, n_steps: int,
         sim0 = env.network.init_state(inp.total_mb, inp.net)
         f0, i0 = lay.pack_state(sim0, inp.state0)
         prow = lay.pack_params(inp)
-        bw = jnp.asarray(inp.bw, jnp.float32)
+        bw = tick_bw(inp.bw, n_steps)(0, n_steps)
 
         # Pallas kernels may not capture non-scalar constants (the CPU
         # frequency/power tables the physics materializes at trace time), so
@@ -768,8 +790,7 @@ def get_runner(controller_code, env_code, cpu: CpuProfile, n_steps: int,
 #     controller-tick alignment (``step_idx % ctrl_every``) survives wave
 #     boundaries;
 #   * a scalar per-lane bandwidth share — one float (the host NIC share for
-#     this wave) instead of an [n_steps] schedule, broadcast across the
-#     wave's ticks.
+#     this wave), broadcast across the wave's ticks by ``tick_bw``.
 #
 # Two wave carry forms exist, selected by ``executor``:
 #
@@ -809,8 +830,7 @@ def build_wave_core(controller, env, cpu: CpuProfile, *, wave_steps: int,
             return carry, m.done
 
         idx = step0 + jnp.arange(wave_steps, dtype=jnp.int32)
-        bw = jnp.broadcast_to(jnp.asarray(inp.bw, jnp.float32),
-                              (wave_steps,))
+        bw = tick_bw(inp.bw, wave_steps)(0, wave_steps)
         (sim, ts), done = jax.lax.scan(wave_step, (sim0, ts0), (idx, bw))
         return sim, ts, _done_at(done, step0)
 
@@ -845,7 +865,7 @@ def build_blocked_wave_core(controller, env, cpu: CpuProfile, *,
             return carry, m.done
 
         idx = step0 + jnp.arange(wave_steps, dtype=jnp.int32)
-        bws = jnp.broadcast_to(jnp.asarray(bw, jnp.float32), (wave_steps,))
+        bws = tick_bw(bw, wave_steps)(0, wave_steps)
         (sim, ts), done = jax.lax.scan(wave_step, (sim0, ts0), (idx, bws))
         f32_out, i32_out = lay.pack_state(sim, ts)
         return f32_out, i32_out, _done_at(done, step0)

@@ -70,10 +70,7 @@ class Combo:
         ctrl = as_controller(req.controller)
         env = as_environment(host.environment)
         ci = ctrl.init(req.datasets, req.profile, host.cpu)
-        inputs = ScanInputs.from_init(ci, req.profile, 1)
-        # Scalar bandwidth share (the wave engine hook) instead of the
-        # [n_steps] schedule single-scenario runs use.
-        inputs = inputs._replace(bw=np.float32(1.0))
+        inputs = ScanInputs.from_init(ci, req.profile)
         self.inputs = jax.tree.map(np.asarray, inputs)
         self.state0 = jax.tree.map(np.asarray, ci.state)
         self.params_row = None         # set by finalize()

@@ -185,7 +185,8 @@ def make_policy_rollout(cfg: PolicyConfig, env, cpu, *, n_steps: int,
         sim0 = env.network.init_state(inp.total_mb, inp.net)
         step = engine.make_step_fn(ctrl, env, cpu, inp, dt=dt,
                                    ctrl_every=ctrl_every, observe=True)
-        xs = (jnp.arange(n_steps, dtype=jnp.int32), inp.bw)
+        xs = (jnp.arange(n_steps, dtype=jnp.int32),
+              engine.tick_bw(inp.bw, n_steps)(0, n_steps))
         (sim, ts), (metrics, obs) = jax.lax.scan(step, (sim0, inp.state0),
                                                  xs)
         return sim, metrics, obs

@@ -114,7 +114,8 @@ def _prepare_lanes(scenarios: Sequence, controller: LearnedController):
     if len(keys) != 1:
         raise ValueError(
             "PG lanes must share one engine code group (same cpu, horizon, "
-            f"dt, controller interval and partition count); got {len(keys)}")
+            "dt, controller interval, partition count and whether a "
+            f"bw_schedule is set); got {len(keys)}")
     stacked = jax.tree.map(lambda *xs: jnp.asarray(np.stack(xs)),
                            *[p.inputs for p in prepared])
     return prepared[0].key, stacked

@@ -214,7 +214,7 @@ def test_vmap_parameter_sweep():
 
     ctrl = api.make_controller("eemt", max_ch=64)
     ci = ctrl.init(MIXED, CHAMELEON, CPU)
-    base = engine.ScanInputs.from_init(ci, CHAMELEON, 600)
+    base = engine.ScanInputs.from_init(ci, CHAMELEON)
     core = engine.build_core(ctrl.code(), api.as_environment(None).code(),
                              CPU, n_steps=600, dt=0.1, ctrl_every=10)
 

@@ -57,7 +57,7 @@ def test_early_exit_matches_full_horizon_runner(n_steps):
     ctrl = api.make_controller("eemt", max_ch=64)
     ci = ctrl.init(MIXED, CHAMELEON, CPU)
     inp = jax.tree.map(np.asarray,
-                       engine.ScanInputs.from_init(ci, CHAMELEON, n_steps))
+                       engine.ScanInputs.from_init(ci, CHAMELEON))
     fast = engine.get_runner(ctrl.code(), ENV, CPU, n_steps, 0.1, 10,
                              batched=False, early_exit=True)
     full = engine.get_runner(ctrl.code(), ENV, CPU, n_steps, 0.1, 10,
@@ -78,7 +78,7 @@ def test_chunking_is_bit_identical():
     ci = ctrl.init(FAST, CHAMELEON, CPU)
     n_steps = 1000
     inp = jax.tree.map(np.asarray,
-                       engine.ScanInputs.from_init(ci, CHAMELEON, n_steps))
+                       engine.ScanInputs.from_init(ci, CHAMELEON))
     outs = []
     for chunk in (64, 333, 1000):
         runner = engine.get_runner(ctrl.code(), ENV, CPU, n_steps, 0.25, 4,

@@ -128,6 +128,31 @@ def test_fetch_bytes_are_the_results_metrics(swept):
         assert done_at.dtype == np.int32 and done_at.size == lanes
 
 
+def test_launch_bytes_are_the_stacked_inputs(swept):
+    """``sweep.launch``'s bytes are what a group hands its runner: each
+    lane's scalars and partition rows, lanes padded to two at least.
+    They follow lanes and partitions, not the horizon: no scenario here
+    has a ``bw_schedule``, so none carries an [n_steps] row."""
+    recs = swept["records"]
+    lanes = {r.span_id: r.meta["lanes"] for r in recs
+             if r.name == "sweep.group"}
+    launched = sorted((lanes[r.parent_id], r.meta["bytes"]) for r in recs
+                      if r.name == "sweep.launch" and "bytes" in r.meta)
+    assert [n for n, _ in launched] == [1, 2, 3]
+
+    def lane_bytes(sc):
+        return sum(leaf.nbytes for leaf in
+                   jax.tree.leaves(_scenario._prepare(sc).inputs))
+
+    scs = scenarios()
+    wsc, me, eemt = scs[5], scs[3], scs[0]
+    assert launched == [(n, max(n, 2) * lane_bytes(sc))
+                        for n, sc in ((1, wsc), (2, me), (3, eemt))]
+    longer = scenarios(total_s=600.0)
+    assert [lane_bytes(sc) for sc in longer] == \
+        [lane_bytes(sc) for sc in scs]
+
+
 def test_spans_on_the_profilers_host_plane(swept):
     path, = glob.glob(os.path.join(swept["log_dir"], "plugins", "profile",
                                    "*", "*.xplane.pb"))

@@ -133,7 +133,7 @@ def test_trace_free_core_carries_the_traces_completion_tick(executor):
     env = api.as_environment(None).code()
     n_steps = 200
     inp = jax.tree.map(np.asarray, engine.ScanInputs.from_init(
-        ctrl.init(THREE, CHAMELEON, CPU), CHAMELEON, n_steps))
+        ctrl.init(THREE, CHAMELEON, CPU), CHAMELEON))
     drained = inp._replace(total_mb=np.zeros_like(inp.total_mb))
     for x in (inp, drained):
         def core(traces, early_exit=True):

@@ -100,8 +100,26 @@ def _widest_group(which):
 @pytest.mark.parametrize("which", ["widest", "tuned"])
 def test_sweep_runner_compiles_at_fig2_size(one_chip, which):
     """The vmapped blocked sweep runner, trace-free as ``sweep`` runs it,
-    at the grid's widest partition count and full horizon."""
+    at the grid's widest partition count and full horizon.  The grid sets
+    no ``bw_schedule``, so each lane carries one bandwidth share."""
     key, stacked = _widest_group(which)
+    assert not key.scheduled
+    assert np.shape(stacked.bw) == np.shape(stacked.total_mb)[:1]
+    runner = engine.get_runner(key.ctrl_code, key.env_code, key.cpu,
+                               key.n_steps, key.dt, key.ctrl_every,
+                               batched=True, traces=False,
+                               executor="blocked")
+    compiled = runner.lower(_shapes(stacked, one_chip)).compile()
+    _fits(compiled)
+
+
+def test_scheduled_sweep_runner_compiles_at_fig2_size(one_chip):
+    """The same runner on the other input rank: the widest group with an
+    [n_steps] bandwidth schedule per lane (``api.tune``'s rungs set one)."""
+    key, stacked = _widest_group("widest")
+    lanes = np.shape(stacked.bw)[0]
+    stacked = stacked._replace(
+        bw=np.ones((lanes, key.n_steps), np.float32))
     runner = engine.get_runner(key.ctrl_code, key.env_code, key.cpu,
                                key.n_steps, key.dt, key.ctrl_every,
                                batched=True, traces=False,

@@ -13,12 +13,12 @@ Rows: fleet/<controller>,us_per_transfer,"<J/GB>;p99=<slowdown>;n=<count>".
 The default trace is 10,000 transfers over 8 hosts at ~80% offered NIC
 load; ``--smoke`` shrinks it to a CI-sized 400 transfers over 4 hosts
 exercising the identical code path (admission, contention rescale, wave
-grouping, bucket padding).
+grouping, occupancy-following wave widths).
 
-``--online`` benchmarks the bounded-memory streaming loop instead
-(``repro.fleet.run_fleet_online``): a diurnal arrival stream of
-HTTP-services-style workloads (many small transfers) consumed through the
-slot-pool wave loop.  Smoke is a 10k-transfer slice (the
+``--online`` drives the same loop through ``repro.fleet.run_fleet_online``
+with bounded memory instead: a diurnal arrival stream of
+HTTP-services-style workloads (many small transfers) consumed through
+256-slot pools.  Smoke is a 10k-transfer slice (the
 ``fleet_online_transfers_per_sec`` perf-gate metric); the full run does a
 100k leg and a 1M leg back to back and records peak host RSS after each —
 the bounded-memory claim, as a BENCH record: ``rss_growth`` is the
@@ -80,9 +80,9 @@ def build(smoke: bool = False):
 def controller_report(report) -> "api.Report":
     """Tabulate ``FleetReport.by_controller`` as a columnar ``api.Report``
     (the same schema the figure grids emit, so ``benchmarks.compare`` and
-    downstream tooling read one format).  Accepts the offline
-    ``FleetReport`` and the online ``OnlineFleetReport`` alike — both
-    expose ``by_controller()`` rows of the same shape."""
+    downstream tooling read one format).  Accepts ``run_fleet``'s
+    ``FleetReport`` and ``run_fleet_online``'s ``OnlineFleetReport`` alike
+    — both expose ``by_controller()`` rows of the same shape."""
     from repro import api
 
     n_transfers = (report.fold.transfers if hasattr(report, "fold")
@@ -107,7 +107,7 @@ def controller_report(report) -> "api.Report":
 def run(smoke: bool = False, json_path: str | None = None,
         warm: bool = False) -> dict:
     """``warm=True`` runs the fleet once untimed first so every wave-runner
-    executable (per controller code x lane bucket) is already compiled when
+    executable (per controller code x wave width) is already compiled when
     the timed run starts.  The CI perf gate uses warm numbers: cold wall is
     dominated by XLA compile time, which jitters far more than the 25%
     tolerance run-to-run."""
@@ -209,8 +209,10 @@ def run_online(smoke: bool = False, json_path: str | None = None,
     n_hosts = 4 if smoke else 8
     if warm:
         # Compile every pool's wave runner off the clock (perf gate
-        # compares steady-state simulation, not XLA compile).
-        _run_online_leg(1_000, n_hosts)
+        # compares steady-state simulation, not XLA compile).  The wave
+        # width follows occupancy, so the warm leg must reach the diurnal
+        # peak that fills the pools: 10k transfers do.
+        _run_online_leg(10_000, n_hosts)
 
     n_main = 10_000 if smoke else 100_000
     report, wall_s = _run_online_leg(n_main, n_hosts)

@@ -1,6 +1,6 @@
 """Run the simulator's main path once on a TPU chip, and check what it gives.
 
-    python chip_smoke.py             # one chip: Figure 2 grid + online fleet
+    python chip_smoke.py             # one chip: Figure 2 grid + fleet
     python chip_smoke.py --chips 4   # the sharded grid and fleet on four
                                      # chips, each against one chip
 
@@ -22,14 +22,16 @@ waves.  Every transfer must complete, the attempt ledger must move exactly
 the offered MB (and the engine's byte counters within ``REL_TOL``), and a
 1,000-transfer prefix run on the chip and on the host CPU must agree on
 the completed set and on totals within ``REL_TOL``.  On the chip, the
-offline ``run_fleet`` (waves in power-of-two lane buckets from 1 up) must
-give that prefix the same bits as the online loop (one 128-lane pool).
+``reference`` wave executor (the pytree scan) must give that prefix the
+same bits as ``blocked`` (the flat-row default), and with NICs that never
+bind, waves of two lanes (two-slot pools) the same answers as waves as
+wide as the occupancy.
 
 ``--chips 4`` runs only the paths that exist across chips: the grid
 through the sharded sweep runner (its 4-lane groups run one lane per
-chip), the online fleet with ``MeshConfig(1, 4)``, and the offline fleet
-prefix with its waves sharded over the chips, each compared bit for bit
-with the same run on one chip of the machine.
+chip) and the whole fleet trace through ``run_fleet(devices=...)`` with
+its waves sharded over the chips, each compared bit for bit with the same
+run on one chip of the machine.
 
 Each phase prints one JSON line (device kind, compile and wall seconds,
 cells or transfers, largest deviation).  The last line of stdout is
@@ -239,26 +241,54 @@ def fleet_phase(jax, fleet, faults_mod, chip, cpu, trace,
           f"{PREFIX}-transfer prefix totals off by {prefix_dev:.3g} "
           f"relative (> {REL_TOL})")
 
-    # The offline driver batches the same lanes differently (power-of-two
-    # buckets, from one lane up); on the chip the bits must not move.
+    # The two wave lowerings agree bit for bit on the chip: the pytree
+    # reference scan against the flat blocked rows.
     t1 = time.perf_counter()
-    offline = fleet.run_fleet(prefix, hosts, wave_s=WAVE_S, dt=DT,
-                              devices=(chip,))
-    offline_wall_s = time.perf_counter() - t1
-    _check_same_transfers("offline run_fleet", "online loop",
-                          offline, on_chip)
+    with jax.default_device(chip):
+        reference = _fleet_run(fleet, prefix, hosts, capacity,
+                               executor="reference", track_transfers=True)
+    reference_wall_s = time.perf_counter() - t1
+    _check_same_transfers("reference executor", "blocked executor",
+                          reference, on_chip)
+
+    # The wave width does not move a lane's bits on the chip.  With NICs
+    # that never bind, no answer depends on who shares a wave; the prefix
+    # runs once with pools that hold every lane (waves as wide as the
+    # occupancy) and once with two slots a pool (every wave two lanes,
+    # the rest queued, so start times move but no answer may).
+    free = tuple(dataclasses.replace(h, nic_mbps=1e9) for h in hosts)
+    lanes: list = []                  # lanes a wave ran, over all pools
+    with jax.default_device(chip):
+        wide = _fleet_run(fleet, prefix, free, capacity,
+                          track_transfers=True,
+                          on_wave=lambda w: lanes.append(w["lanes"]))
+        narrow = _fleet_run(fleet, prefix, free, 2, track_transfers=True)
+    check(narrow.counters["peak_queue_depth"] > 0,
+          "the two-slot pools never queued a request")
+    answers = {t.name: (t.completed, t.time_s, t.energy_j, t.moved_mb)
+               for t in wide.transfers}
+    differ = [t.name for t in narrow.transfers
+              if answers.get(t.name) != (t.completed, t.time_s, t.energy_j,
+                                         t.moved_mb)]
+    check(not differ and len(answers) == len(narrow.transfers),
+          f"two-lane waves differ from occupancy-wide ones (up to "
+          f"{max(lanes)} lanes a wave) in {len(differ)} of {len(answers)} "
+          f"transfers: {differ[:4]}")
 
     return {"phase": "fleet", "device_kind": chip.device_kind,
             "transfers": len(trace), "hosts": len(hosts),
             "pool_capacity": capacity,
             "peak_in_flight": rep.counters["peak_in_flight"],
+            "lanes_run": rep.counters["lanes_run"],
             "waves": rep.waves, "compile_s": compile_s, "wall_s": wall_s,
             "offered_mb": offered, "ledger_goodput_mb": churn["goodput_mb"],
             "counter_rel_dev": counter_dev,
             "prefix_transfers": len(prefix),
             "prefix_max_rel_dev_vs_cpu": prefix_dev,
-            "prefix_offline_wall_s": offline_wall_s,
-            "prefix_offline_eq_online": True}
+            "prefix_reference_wall_s": reference_wall_s,
+            "prefix_reference_eq_blocked": True,
+            "prefix_wide_peak_lanes": max(lanes),
+            "prefix_two_lane_waves_eq_wide": True}
 
 
 def _check_same_transfers(what: str, against: str, a, b) -> None:
@@ -300,43 +330,24 @@ def grid_sharded_phase(api, chips, exp) -> dict:
             "max_rel_dev_vs_one_chip": 0.0}
 
 
-def fleet_sharded_phase(jax, fleet, sharding, chips, trace,
-                        hosts) -> dict:
-    """The fleet with a mesh over the chips, bit for bit against one
-    chip."""
-    capacity = sum(h.slots for h in hosts)
-    mesh = sharding.MeshConfig(1, len(chips))
+def fleet_sharded_phase(fleet, chips, trace, hosts) -> dict:
+    """The fleet with its waves sharded over the chips, bit for bit
+    against one chip."""
     c0, t0 = compile_clock(), time.perf_counter()
-    sharded = _fleet_run(fleet, trace, hosts, capacity, mesh=mesh,
-                         track_transfers=True)
+    sharded = fleet.run_fleet(trace, hosts, wave_s=WAVE_S, dt=DT,
+                              devices=chips)
     wall_s = time.perf_counter() - t0
     compile_s = compile_clock() - c0
-    with jax.default_device(chips[0]):
-        one = _fleet_run(fleet, trace, hosts, capacity,
-                         track_transfers=True)
+    one = fleet.run_fleet(trace, hosts, wave_s=WAVE_S, dt=DT,
+                          devices=chips[:1])
     check(sharded.completed == len(trace),
           f"sharded fleet: {len(trace) - sharded.completed} transfers did "
           f"not complete")
-    _check_same_transfers("sharded online fleet", "one chip", sharded, one)
-
-    # The offline driver shards each wave of at least one lane per chip.
-    prefix = trace[:PREFIX]
-    c1, t1 = compile_clock(), time.perf_counter()
-    offline = fleet.run_fleet(prefix, hosts, wave_s=WAVE_S, dt=DT,
-                              devices=chips)
-    offline_wall_s = time.perf_counter() - t1
-    offline_compile_s = compile_clock() - c1
-    offline_one = fleet.run_fleet(prefix, hosts, wave_s=WAVE_S, dt=DT,
-                                  devices=chips[:1])
-    _check_same_transfers("sharded offline fleet", "one chip", offline,
-                          offline_one)
+    _check_same_transfers("sharded fleet", "one chip", sharded, one)
     return {"phase": "fleet_sharded", "device_kind": chips[0].device_kind,
             "chips": len(chips), "transfers": len(trace),
-            "pool_capacity": capacity, "compile_s": compile_s,
-            "wall_s": wall_s, "max_rel_dev_vs_one_chip": 0.0,
-            "offline_prefix_transfers": len(prefix),
-            "offline_compile_s": offline_compile_s,
-            "offline_wall_s": offline_wall_s}
+            "compile_s": compile_s, "wall_s": wall_s,
+            "max_rel_dev_vs_one_chip": 0.0}
 
 
 def main(argv=None) -> None:
@@ -376,7 +387,6 @@ def main(argv=None) -> None:
     from benchmarks import fleet as fleet_bench
     from benchmarks.common import use_compile_cache
     from repro import api, fleet
-    from repro.distributed import sharding
     from repro.workloads import faults
 
     use_compile_cache()
@@ -389,8 +399,7 @@ def main(argv=None) -> None:
                            hosts))
     else:
         report(grid_sharded_phase(api, chips, exp))
-        report(fleet_sharded_phase(jax, fleet, sharding, chips, trace,
-                                   hosts))
+        report(fleet_sharded_phase(fleet, chips, trace, hosts))
     print(json.dumps({"ok": True, "device": {
         "platform": chips[0].platform, "kind": chips[0].device_kind,
         "count": len(chips)}}))

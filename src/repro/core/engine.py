@@ -906,9 +906,10 @@ def get_wave_runner(controller_code, env_code, cpu: CpuProfile,
 
     ``donate=True`` donates the state-carry buffers (the flat f32/i32 rows
     on ``blocked``, the SimState/TunerState pytrees on ``reference``) —
-    what the online fleet's persistent slot pools want: the pool's whole
-    ``[capacity, ...]`` arrays flow through every wave, so donation makes
-    the wave an in-place update instead of an alloc-and-copy.  Callers must
+    what the fleet loop's persistent slot pools want: the pool's first
+    rows, as many as the wave is wide, flow through every wave, so
+    donation makes the wave an in-place update instead of an
+    alloc-and-copy.  Callers must
     then treat the passed-in buffers as consumed.  Slot recycling composes
     with the wave contract for free: a retired slot's rows are zeroed
     (born-drained no-op lane) until the next admission overwrites them with
@@ -945,9 +946,9 @@ def get_sharded_wave_runner(controller_code, env_code, cpu: CpuProfile,
                             n_partitions: Optional[int] = None):
     """Wave runner sharded over ``devices`` along the lane axis.
 
-    Same contract as :func:`get_wave_runner`; lane batches must be padded to
-    a multiple of ``len(devices)`` (``repro.distributed.sharding.pad_batch``
-    with ``fill="zero"`` adds drained no-op lanes).  The carry buffers are
+    Same contract as :func:`get_wave_runner`; the lane count must be a
+    multiple of ``len(devices)`` (the fleet loop rounds a sharded wave's
+    width up to one; zeroed lanes are drained no-ops).  The carry buffers are
     donated — each wave consumes the previous wave's output states (the
     flat f32/i32 state rows on the ``blocked`` path, the SimState/TunerState
     pytrees on ``reference``).

@@ -44,11 +44,11 @@ class MeshConfig:
 
     Describes a fleet's execution substrate as ``num_hosts`` processes of
     ``devices_per_host`` accelerators each, flattened into a single 1-D
-    ``batch`` mesh for the slot-pool wave runners.  The online fleet loop
+    ``batch`` mesh for the slot-pool wave runners.  The fleet loop
     (``repro.fleet.online``) treats the config as the *logical* mesh:
     admission and slot assignment run on host 0 (deterministic — every
     lane's slot index is a pure function of the arrival stream, so all
-    hosts agree on the broadcast layout), and slot pools are padded to a
+    hosts agree on the broadcast layout), and a sharded wave's width is a
     multiple of the mesh size so ``shard_batch`` placements divide evenly.
 
     ``None`` fields auto-detect: one host, all local devices.  ``.devices()``
@@ -106,8 +106,8 @@ def batch_mesh(devices=None) -> Mesh:
 
 def should_shard(lanes: int, devices) -> bool:
     """Whether a batch of ``lanes`` engine lanes runs sharded over
-    ``devices`` (``repro.api.sweep`` groups and ``repro.fleet.run_fleet``
-    waves alike).
+    ``devices`` (``repro.api.sweep`` groups and the fleet loop's waves
+    alike; a fleet wave counts its occupied slots).
 
     Only when every device gets at least one lane: a smaller batch would
     pay padding lanes plus an extra compiled executable for no wall-clock
@@ -128,9 +128,10 @@ def pad_batch(tree, multiple: int, *, fill: str = "repeat"):
       well-behaved for sweep groups, where a padding lane simulates a
       duplicate scenario and the group's early-exit loop waits for it to
       finish like any other lane.
-    * ``"zero"`` appends zero rows — what the fleet wave scheduler wants: a
-      zeroed engine lane has no bytes remaining, so it is born drained and
-      frozen from tick 0, costing nothing.
+    * ``"zero"`` appends zero rows: a zeroed engine lane has no bytes
+      remaining, so it is born drained and frozen from tick 0.  The fleet
+      loop needs no padding (its slot pools hold the widest wave's rows,
+      zeroed where free), so no caller in the package uses it.
     """
     if fill not in ("repeat", "zero"):
         raise ValueError(f"unknown fill mode {fill!r}")

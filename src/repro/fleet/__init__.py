@@ -9,8 +9,8 @@ NIC whose capacity is split among its in-flight transfers — on top of the
 
 Execution is in streaming *waves*: all active transfers advance by one wave
 window through the grouped ``jit(vmap(scan))`` engine (one launch per
-(controller code, environment code, cpu) group, lanes padded to
-shape-compatible buckets), completed lanes are drained and refilled from
+(controller code, environment code, cpu) group, as wide as the group's
+occupancy in powers of two), completed lanes are drained and refilled from
 the arrival queue, and per-host NIC contention rescales each transfer's
 available bandwidth between waves.  Pools may be heterogeneous: every
 :class:`Host` carries its own CPU profile and its own
@@ -31,10 +31,11 @@ Quickstart::
     report = fleet.run_fleet(trace, hosts, wave_s=30.0, dt=0.1)
     print(report.summary())
 
-For *unbounded* arrival streams — online operation with fixed host memory
-regardless of stream length — see :func:`run_fleet_online`
-(``repro.fleet.online``) and the stream adapters (``poisson_stream``,
-``diurnal_stream``, ``replay_stream``).
+There is one fleet loop, :func:`run_fleet_online` (``repro.fleet.online``):
+it takes *unbounded* arrival streams with fixed host memory regardless of
+stream length (see the stream adapters ``poisson_stream``,
+``diurnal_stream``, ``replay_stream``), and ``run_fleet`` runs it on a
+finite trace with nothing bounded, keeping every per-transfer record.
 """
 from .aggregates import (FleetFold, FleetReport,  # noqa: F401
                          FleetTransfer, OnlineFleetReport, QuantileSketch)
@@ -42,9 +43,8 @@ from .arrivals import (TransferRequest, diurnal_stream,  # noqa: F401
                        poisson_stream, poisson_trace, replay_stream,
                        replay_trace)
 from .hosts import Host, host_pool  # noqa: F401
-from .online import OnlineConfig, run_fleet_online  # noqa: F401
+from .online import OnlineConfig, run_fleet, run_fleet_online  # noqa: F401
 from .ringbuf import SlotPool  # noqa: F401
-from .scheduler import run_fleet  # noqa: F401
 
 __all__ = [
     "FleetFold", "FleetReport", "FleetTransfer", "Host", "OnlineConfig",

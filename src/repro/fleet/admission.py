@@ -1,9 +1,8 @@
-"""Admission and rescale logic shared by the offline and online fleets.
+"""Admission and rescale decisions of the fleet loop.
 
-``repro.fleet.scheduler.run_fleet`` (whole-trace, offline) and
-``repro.fleet.online.run_fleet_online`` (unbounded-stream, bounded-memory)
-make exactly the same scheduling decisions; this module is the single
-implementation both call:
+``repro.fleet.online.run_fleet_online`` — the fleet loop, which
+``run_fleet`` runs on a finite trace — makes its scheduling decisions
+through these functions:
 
 * :class:`Combo` — prepared admission state for one unique
   (controller, datasets, profile, cpu, environment) combination: the packed
@@ -25,11 +24,9 @@ implementation both call:
   off the killed lane's state row (so byte conservation telescopes
   bit-exactly), under ``restart="scratch"`` the original datasets.
 
-Because both loops share these functions *and* the engine wave runners, a
-trace executed online (with capacity/watermarks large enough never to bind)
-is bit-identical per transfer to the offline ``run_fleet`` of the same
-trace — tested in tests/test_fleet_online.py, alongside a golden-value
-regression pinning the offline path to its pre-refactor numbers.
+Every decision is a function of (arrival time, request content) and the
+loop's state, never of trace order; tests/test_fleet.py pins the
+per-transfer numbers they lead to with golden values.
 """
 from __future__ import annotations
 

@@ -219,9 +219,9 @@ class ExactSum:
     ``add`` maintains a list of non-overlapping partials whose exact sum is
     the exact sum of everything added; ``value`` rounds it once, via
     ``math.fsum`` over the partials.  The result is therefore *independent
-    of accumulation order* — the property that lets the online loop, which
-    folds transfers in retirement order, reproduce the offline
-    ``math.fsum`` totals (taken in sorted-trace order) bit-for-bit.  The
+    of accumulation order* — the property that lets the fleet loop, which
+    folds transfers in retirement order, reproduce ``FleetReport``'s
+    ``math.fsum`` totals over the same records bit-for-bit.  The
     partials list stays tiny (its length is bounded by the exponent spread
     of the inputs, ~40 entries for fleet magnitudes), so memory is O(1).
     """
@@ -370,15 +370,15 @@ class FleetFold:
 
     Totals (energy, GB, joules/GB, per-controller sums and means) are
     *exact* — :class:`ExactSum` makes them independent of fold order, so
-    they bit-match the offline ``FleetReport`` of the same transfers.
+    they bit-match a ``FleetReport`` of the same transfers.
     Percentile fields come from :class:`QuantileSketch` and carry its
     documented ``rel_err`` relative-error tolerance instead.
 
     ``slo_s`` arms per-request latency SLO tracking: response-time
     percentiles stream through a latency sketch (same ``rel_err``
-    tolerance vs the offline ``FleetReport.latencies()``), and the
-    violation *count* — a transfer that missed the SLO or never completed
-    — is an integer, bit-equal to the offline count.
+    tolerance vs ``FleetReport.latencies()``), and the violation *count*
+    — a transfer that missed the SLO or never completed — is an integer,
+    bit-equal to ``FleetReport``'s.
     """
 
     def __init__(self, rel_err: float = 0.01,

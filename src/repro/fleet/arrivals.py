@@ -2,8 +2,8 @@
 
 A **trace** is a tuple of :class:`TransferRequest` — plain frozen metadata;
 all numeric state lives in the engine once the scheduler admits the
-request.  Two constructors cover the workload classes the offline fleet
-layer targets:
+request.  Two constructors cover the workload classes ``run_fleet``
+targets:
 
 * :func:`poisson_trace` — synthetic open-loop arrivals (exponential
   inter-arrival gaps from a seeded generator, controllers/datasets cycled
@@ -16,8 +16,8 @@ Both are deterministic: the same inputs produce the same trace, and
 arrival time with a content tie-break), so shuffling a trace never changes
 fleet totals.
 
-A **stream** is the online analogue (``repro.fleet.online``): a plain
-Python generator yielding :class:`TransferRequest` in nondecreasing
+A **stream** is what the fleet loop consumes (``repro.fleet.online``): a
+plain Python generator yielding :class:`TransferRequest` in nondecreasing
 ``arrival_s`` order, possibly unbounded.  Three adapters mirror the trace
 constructors:
 
@@ -79,7 +79,7 @@ class TransferRequest:
 def request_sort_key(req: TransferRequest) -> tuple:
     """Canonical ordering: arrival time, then the request's FULL content.
 
-    The scheduler sorts the trace with this key so host assignment — and
+    ``run_fleet`` sorts the trace with this key so host assignment — and
     therefore every downstream number — is a function of what arrived when,
     not of the order the caller happened to build the list in.  Every field
     that can influence a result participates (full dataset shapes, the
@@ -275,8 +275,8 @@ def replay_stream(requests: Iterable[TransferRequest],
     Yields items unchanged, checking nondecreasing ``arrival_s`` as the
     stream is consumed — the online loop's admission clock only moves
     forward, so an out-of-order arrival would be silently starved instead
-    of scheduled.  Feed it a sorted offline trace for online/offline
-    parity runs, or a lazy log parser for replay at scale.
+    of scheduled.  Feed it a sorted trace, or a lazy log parser for
+    replay at scale.
     """
     last = -math.inf
     for i, req in enumerate(requests):

@@ -10,13 +10,13 @@ concurrent transfer processes it will run), and a shared NIC.
 The NIC is the contention point: when the per-flow bandwidth demands of a
 host's in-flight transfers exceed ``nic_mbps``, every transfer on that host
 has its available bandwidth rescaled proportionally for the next wave (see
-``repro.fleet.scheduler``).  When total demand fits, transfers run exactly
+``repro.fleet.online``).  When total demand fits, transfers run exactly
 as they would alone — the zero-contention fleet path is bit-identical to
 independent ``api.run`` calls.
 
 Heterogeneous pools mix hosts with different CPUs *and* different
 environments (a lossy-WAN satellite site next to a clean-path datacenter,
-big.LITTLE edge boxes next to Haswell servers); the scheduler groups wave
+big.LITTLE edge boxes next to Haswell servers); the fleet loop groups wave
 lanes by (controller code, environment code, cpu), so each distinct physics
 compiles its own executable and lanes still batch within it.
 """

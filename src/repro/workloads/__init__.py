@@ -2,8 +2,8 @@
 
 ``repro.fleet`` runs traces and streams of transfers; this package supplies
 the workloads an operator actually faces, each expressed in the fleet
-layer's existing vocabulary so both drivers (offline ``run_fleet``, online
-``run_fleet_online``) consume them unchanged:
+layer's existing vocabulary so the fleet loop (``run_fleet_online``, and
+``run_fleet`` on a finite trace) consumes them unchanged:
 
 * :mod:`repro.workloads.http` — HTTP-service request streams: closed-loop
   users issuing many small transfers, persistent-connection reuse (cold
@@ -21,7 +21,7 @@ layer's existing vocabulary so both drivers (offline ``run_fleet``, online
   ``make_environment("logfit", log=...)``.
 
 Import direction: this package imports ``repro.fleet`` and ``repro.api``;
-neither imports it back (the fleet drivers take fault schedules
+neither imports it back (the fleet loop takes fault schedules
 duck-typed, and the ``logfit`` registry entry is a lazy factory).
 """
 from .faults import (ChurnFold, FaultSchedule, HostDown,  # noqa: F401

@@ -19,13 +19,12 @@ Killed transfers re-enter the admission queue through the shared
 the requeued request carries only the partitions' *remaining* bytes (the
 semantics ``repro.ckpt`` restarts give training jobs — finished work is
 kept); under ``restart="scratch"`` the full original request is requeued
-and everything already moved is wasted.  Both fleet drivers
-(``repro.fleet.scheduler.run_fleet`` and
-``repro.fleet.online.run_fleet_online``) apply the schedule *between
-waves*, at identical points of their loops, so the same seed produces
-bit-identical reports offline and online.
+and everything already moved is wasted.  The fleet loop
+(``repro.fleet.online.run_fleet_online``, which ``run_fleet`` runs on a
+finite trace) applies the schedule *between waves*, after ingest and
+before admission, so the same seed produces bit-identical reports.
 
-The schedule is pure data: the drivers interrogate it with
+The schedule is pure data: the loop interrogates it with
 :meth:`FaultSchedule.down_hosts` / :meth:`nic_caps` / :meth:`kills_in`
 (all pure functions of simulated time) and account attempts through the
 :class:`ChurnFold` it hands out — so ``repro.fleet`` never imports this
@@ -36,8 +35,8 @@ moved bytes are fed as their raw per-partition float32 components
 (``offered`` positively, ``remaining`` negatively) into order-independent
 :class:`repro.fleet.aggregates.ExactSum` accumulators, so the telescoping
 identity *offered == goodput* for a fully-completed resume-mode run holds
-**bit-exactly**, independent of kill timing, wave order, or which driver
-ran the fleet.  ``FaultSchedule.generate`` builds a random schedule from a
+**bit-exactly**, independent of kill timing, wave order, or wave
+width.  ``FaultSchedule.generate`` builds a random schedule from a
 seed (per-host Poisson outage/degrade processes) that is a pure function
 of its arguments.
 """
@@ -124,7 +123,7 @@ class FaultSchedule:
             raise ValueError(f"restart must be one of {_RESTART_MODES}, "
                              f"got {self.restart!r}")
 
-    # ------------------------------------------------- driver interface --
+    # --------------------------------------------------- loop interface --
 
     def down_hosts(self, t0: float, t1: float) -> frozenset:
         """Hosts down at any point of the wave ``[t0, t1)``."""
@@ -149,14 +148,14 @@ class FaultSchedule:
         return caps
 
     def kills_in(self, t0: float, t1: float) -> frozenset:
-        """Transfer names with a kill event in ``(t0, t1]`` — the drivers
+        """Transfer names with a kill event in ``(t0, t1]`` — the loop
         pass the previous and current wave boundaries, so every kill fires
         exactly once even across idle fast-forward jumps."""
         return frozenset(e.name for e in self.events
                          if isinstance(e, KillTransfer) and t0 < e.t <= t1)
 
     def churn_fold(self) -> "ChurnFold":
-        """The attempt ledger a driver folds kills/retirements into."""
+        """The attempt ledger the loop folds kills/retirements into."""
         return ChurnFold(restart=self.restart)
 
     # -------------------------------------------------------- generation --
@@ -214,7 +213,7 @@ class ChurnFold:
     independent of accumulation order and the components telescope
     (``resume`` re-offers exactly the float32 remainders of the killed
     attempt), a fully-completed resume-mode run satisfies
-    ``goodput_mb == offered_mb`` **bit-exactly** in either fleet driver.
+    ``goodput_mb == offered_mb`` **bit-exactly**.
 
     Memory is bounded: the only per-name state is ``_pending``, holding the
     killed attempts of transfers currently awaiting their final retirement

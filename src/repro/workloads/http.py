@@ -5,7 +5,7 @@ HTTP-style service is the opposite corner — a closed population of users
 issuing streams of *small* requests with think time between them, where
 connection handling dominates.  :func:`http_request_stream` renders that
 workload as ordinary :class:`repro.fleet.TransferRequest` items, so it
-flows through both fleet drivers (and the engine wave runners) unchanged:
+flows through the fleet loop (and the engine wave runners) unchanged:
 
 * **Persistent connections.**  Each user holds one connection.  A request
   arriving within ``keepalive_s`` of the previous response reuses it
@@ -33,7 +33,7 @@ keyed (time, user) — the stream is a pure function of the
 :class:`HttpService` spec.  Request payloads are drawn from a small
 quantized size menu, not a continuum: the admission layer caches one
 prepared :class:`repro.fleet.admission.Combo` per unique dataset tuple,
-so a bounded size menu keeps the online loop's memory bounded over an
+so a bounded size menu keeps the fleet loop's memory bounded over an
 unbounded stream.
 """
 from __future__ import annotations
@@ -71,8 +71,8 @@ class ServiceLevel:
                              f"{self.max_violation_rate}")
 
     def evaluate(self, report) -> dict:
-        """Judge a fleet report (offline or online, run with
-        ``slo_s=self.latency_s``) against this service level."""
+        """Judge a fleet report (``run_fleet``'s or ``run_fleet_online``'s,
+        run with ``slo_s=self.latency_s``) against this service level."""
         rate = report.slo_violation_rate()
         return {
             "latency_slo_s": self.latency_s,
@@ -147,7 +147,7 @@ def http_request_stream(service: HttpService, *,
                         ) -> Iterator[TransferRequest]:
     """Closed-loop request stream for ``service``, in arrival order.
 
-    Yields :class:`TransferRequest` items ready for either fleet driver.
+    Yields :class:`TransferRequest` items ready for the fleet loop.
     A warm request carries one payload partition; a cold one an extra
     ``conn-setup`` partition first (so any ``max_partitions >= 2`` admits
     it).  ``n_requests`` bounds the stream for tests/benchmarks; ``None``
@@ -199,9 +199,8 @@ def http_request_trace(service: HttpService, *, n_requests: int,
                        name_prefix: str = "http",
                        ) -> tuple:
     """Materialized finite trace: ``n_requests`` items of
-    :func:`http_request_stream` as a tuple, for the offline ``run_fleet``
-    (already in arrival order, so it also feeds ``replay_stream`` for
-    offline/online parity runs)."""
+    :func:`http_request_stream` as a tuple, for ``run_fleet`` (already in
+    arrival order, so it also feeds ``run_fleet_online`` as it is)."""
     if n_requests <= 0:
         raise ValueError(f"n_requests must be positive, got {n_requests}")
     return tuple(http_request_stream(service, n_requests=n_requests,

@@ -1,17 +1,22 @@
-"""Online fleet loop: offline parity, bounded memory, backpressure.
+"""The fleet loop on streams: parity with api.run, bounded memory,
+backpressure.
 
-The load-bearing contracts (ISSUE acceptance criteria):
+The load-bearing contracts:
 
-* **Offline parity** — feeding a sorted finite trace through the online
-  loop with capacity/watermarks that never bind reproduces ``run_fleet``'s
-  per-transfer results bit-for-bit, and the exact streaming totals
-  bit-equal the offline ``math.fsum`` totals.  Only the percentile fields
-  carry the quantile sketch's documented relative-error tolerance.
+* **Independent parity** — a transfer that never sees contention gives
+  the bits of an independent ``api.run`` of the same scenario, while the
+  wave width follows the pools' occupancy; the exact streaming totals
+  bit-equal ``math.fsum`` over the per-transfer records.  Only the
+  percentile fields carry the quantile sketch's documented
+  relative-error tolerance.
+* **Executor parity** — ``reference`` and ``blocked`` agree transfer by
+  transfer under contention, with a bounded pool that queues.
 * **Bounded memory** — slot pools recycle in place (a 1-slot pool still
   completes everything), ingest backpressure bounds the waiting queue,
   and on a forced multi-device host peak RSS does not scale with stream
   length (subprocess test, mirroring tests/test_fleet_sharded.py).
 """
+import math
 import os
 import subprocess
 import sys
@@ -25,6 +30,8 @@ from repro.core.types import CHAMELEON, DatasetSpec
 FAST = (DatasetSpec("a", 200, 400.0, 2.0),
         DatasetSpec("b", 10, 600.0, 60.0))
 ONE = (DatasetSpec("c", 50, 500.0, 10.0),)
+BIG = (DatasetSpec("a", 2000, 4000.0, 2.0),
+       DatasetSpec("b", 100, 6000.0, 60.0))
 NO_CONTENTION = 1e9
 
 HOSTS = dict(nic_mbps=CHAMELEON.bandwidth_mbps, slots=4)
@@ -39,33 +46,92 @@ def _trace(n=24, seed=11):
 
 # ---------------------------------------------------------------- parity --
 
+def _solo(req, dt):
+    """An independent api.run of one request's scenario."""
+    return api.run(api.Scenario(profile=req.profile, datasets=req.datasets,
+                                controller=req.controller,
+                                total_s=req.total_s, dt=dt))
+
+
 def test_online_matches_offline_bit_exactly_on_shared_trace():
-    """Same trace, generous capacity: per-transfer records identical."""
+    """Zero contention, occupancy that grows and shrinks: every transfer
+    equals its own api.run, and the fold's exact totals equal fsum over
+    the records."""
     trace = _trace()
-    hosts = fleet.host_pool(2, **HOSTS)
-    off = fleet.run_fleet(trace, hosts, wave_s=10.0, dt=0.5)
+    hosts = fleet.host_pool(2, nic_mbps=NO_CONTENTION, slots=4)
+    seen = []
     on = fleet.run_fleet_online(trace, hosts, wave_s=10.0, dt=0.5,
-                                pool_capacity=64, track_transfers=True)
+                                pool_capacity=64, track_transfers=True,
+                                on_wave=seen.append)
 
-    assert on.fold.transfers == len(off.transfers) == len(trace)
-    got = {t.name: t for t in on.transfers}
-    for t in off.transfers:
-        assert got[t.name] == t          # frozen dataclass: bit-exact
-    # Exact streaming totals == offline fsum totals, no tolerance.
-    assert on.total_energy_j == off.total_energy_j
-    assert on.total_gb == off.total_gb
-    assert on.completed == off.completed
-    assert on.sim_s == off.sim_s
-    assert on.waves == off.waves
+    assert on.fold.transfers == len(on.transfers) == len(trace)
+    assert len({s["lanes"] for s in seen}) > 1     # the width moved
+    by_name = {r.name: r for r in trace}
+    solos = {}
+    for t in on.transfers:
+        req = by_name[t.name]
+        key = (req.controller, req.datasets)
+        if key not in solos:
+            solos[key] = _solo(req, 0.5)
+        solo = solos[key]
+        assert t.completed and solo.completed
+        assert (t.time_s, t.energy_j) == (solo.time_s, solo.energy_j), \
+            t.name                          # bit-exact, no tolerance
+    # Exact streaming totals == fsum over the records, no tolerance.
+    assert on.total_energy_j == math.fsum(t.energy_j for t in on.transfers)
+    assert on.total_gb == math.fsum(t.moved_mb for t in on.transfers) / 1024
+    assert on.completed == len(trace)
     assert on.dropped == 0
+    vals = np.asarray([t.slowdown for t in on.transfers])
+    sketch = on.slowdowns()
+    for q, key in ((0.50, "p50"), (0.95, "p95"), (0.99, "p99")):
+        ref = float(np.percentile(vals, 100 * q, method="inverted_cdf"))
+        assert abs(sketch[key] - ref) <= 0.0101 * ref + 1e-12, (key, ref)
 
-    # Per-controller exact fields bit-match the offline breakdown.
-    ob, nb = off.by_controller(), on.by_controller()
-    assert set(ob) == set(nb)
-    for name in ob:
-        for key in ("transfers", "completed", "energy_j", "gb",
-                    "joules_per_gb", "mean_time_s", "mean_wait_s"):
-            assert nb[name][key] == ob[name][key], (name, key)
+    # Per-controller exact fields of the fold bit-match FleetReport's fsum
+    # breakdown over the same records, also on the contended trace.
+    contended = fleet.run_fleet_online(trace, fleet.host_pool(2, **HOSTS),
+                                       wave_s=10.0, dt=0.5, pool_capacity=64,
+                                       track_transfers=True)
+    assert any(t.wait_s > 0 for t in contended.transfers)
+    for rep in (on, contended):
+        ref = fleet.FleetReport(transfers=rep.transfers,
+                                host_stats=rep.host_stats, sim_s=rep.sim_s,
+                                waves=rep.waves, wave_s=10.0, dt=0.5,
+                                dropped=0)
+        ob, nb = ref.by_controller(), rep.by_controller()
+        assert set(ob) == set(nb) and len(ob) > 1
+        for name in ob:
+            for key in ("transfers", "completed", "energy_j", "gb",
+                        "joules_per_gb", "mean_time_s", "mean_wait_s"):
+                assert nb[name][key] == ob[name][key], (name, key)
+        assert rep.total_energy_j == ref.total_energy_j
+        assert rep.total_gb == ref.total_gb
+
+
+def test_wave_width_follows_highest_occupied_slot():
+    """One group: a long lane holds slot 0, four short ones fill slots 1-4
+    for one wave and retire.  The wave runs 2, then 8, then 2 lanes again,
+    and no transfer's bits move with it."""
+    long = fleet.TransferRequest(arrival_s=0.0, datasets=BIG,
+                                 controller="me", profile=CHAMELEON,
+                                 name="long", total_s=600.0)
+    short = [fleet.TransferRequest(arrival_s=1.0, datasets=ONE,
+                                   controller="me", profile=CHAMELEON,
+                                   name=f"s{i}", total_s=600.0)
+             for i in range(4)]
+    seen = []
+    rep = fleet.run_fleet_online([long] + short,
+                                 fleet.host_pool(1, nic_mbps=NO_CONTENTION),
+                                 wave_s=5.0, dt=0.5, track_transfers=True,
+                                 on_wave=seen.append)
+    lanes = [s["lanes"] for s in seen]
+    assert lanes[:3] == [2, 8, 2] and set(lanes[2:]) == {2}, lanes
+    for req in [long] + short:
+        (t,) = [t for t in rep.transfers if t.name == req.name]
+        solo = _solo(req, 0.5)
+        assert (t.completed, t.time_s, t.energy_j) == \
+            (solo.completed, solo.time_s, solo.energy_j), req.name
 
 
 def test_online_percentiles_within_sketch_tolerance():
@@ -117,16 +183,19 @@ def test_empty_stream():
 
 
 def test_stream_shorter_than_one_wave():
-    """A single sub-wave transfer: online == offline, one wave runs."""
+    """A single sub-wave transfer: one wave runs, and its record equals an
+    independent api.run."""
     req = fleet.TransferRequest(arrival_s=0.0, datasets=ONE,
                                 controller="wget/curl", profile=CHAMELEON,
                                 name="tiny", total_s=600.0)
     hosts = fleet.host_pool(1, nic_mbps=NO_CONTENTION)
-    off = fleet.run_fleet([req], hosts, wave_s=30.0, dt=0.1)
     on = fleet.run_fleet_online([req], hosts, wave_s=30.0, dt=0.1,
                                 track_transfers=True)
-    assert on.transfers[0] == off.transfers[0]
-    assert on.total_energy_j == off.total_energy_j
+    (t,) = on.transfers
+    solo = _solo(req, 0.1)
+    assert (t.completed, t.time_s, t.energy_j) == \
+        (solo.completed, solo.time_s, solo.energy_j)
+    assert on.total_energy_j == t.energy_j
     assert on.waves == 1
 
 
@@ -164,7 +233,7 @@ def test_horizon_cut_reports_dropped():
                                                  slots=1),
                                  wave_s=5.0, dt=0.1, horizon_s=10.0)
     assert rep.dropped > 0
-    # Unlike offline, the stream is consumed lazily: arrivals past the
+    # Unlike run_fleet's, the stream is consumed lazily: arrivals past the
     # horizon are never ingested, so dropped counts only the queued ones.
     assert rep.fold.transfers + rep.dropped <= len(trace)
     assert rep.sim_s == 10.0
@@ -200,10 +269,26 @@ def test_on_wave_observability_callback():
 
 # ------------------------------------------------------------ validation --
 
-def test_reference_executor_rejected():
-    with pytest.raises(ValueError, match="blocked wave contract"):
-        fleet.run_fleet_online(_trace(n=2), fleet.host_pool(1, **HOSTS),
-                               executor="reference")
+def test_reference_executor_matches_blocked_under_contention():
+    """The reference wave path runs in the loop and equals blocked,
+    transfer by transfer: shared NICs (shares < 1), host slot budgets and
+    a 2-slot pool that makes requests queue."""
+    reqs = [fleet.TransferRequest(arrival_s=0.3 * i, datasets=FAST,
+                                  controller=c, profile=CHAMELEON,
+                                  name=f"t{i}-{c}", total_s=240.0)
+            for i in range(4) for c in ("eemt", "me")]
+    hosts = fleet.host_pool(2, nic_mbps=800.0, slots=3)
+    reps = {ex: fleet.run_fleet_online(reqs, hosts, wave_s=5.0, dt=0.1,
+                                       pool_capacity=2, executor=ex,
+                                       track_transfers=True)
+            for ex in ("reference", "blocked")}
+    a, b = reps["reference"], reps["blocked"]
+    assert a.counters["peak_queue_depth"] > 0
+    assert any(t.wait_s > 0 for t in b.transfers)
+    assert b.completed == len(reqs)
+    assert a.transfers == b.transfers        # frozen records: bit-exact
+    assert a.host_stats == b.host_stats
+    assert (a.sim_s, a.waves) == (b.sim_s, b.waves)
 
 
 def test_too_many_partitions_names_the_knob():

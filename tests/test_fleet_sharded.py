@@ -23,7 +23,7 @@ BIG = (DatasetSpec("a", 2000, 4000.0, 2.0),)
 MIX = tuple(DatasetSpec(d.name, d.num_files // 100, d.total_mb / 100,
                         d.avg_file_mb) for d in MIXED)
 hosts = fleet.host_pool(6, nic_mbps=1e9)
-# 6 lanes -> bucket 8 over 4 devices; 4 lanes -> one per device (the
+# 6 lanes -> width 8 over 4 devices; 4 lanes -> one per device (the
 # multi-partition datasets are where a batch-width-dependent partition sum
 # would show); 3 lanes -> fewer lanes than devices, so unsharded.
 for n, datasets, sharded in ((6, BIG, 1), (4, MIX, 1), (3, MIX, 0)):

@@ -1,7 +1,7 @@
 """``partition_sum``: per-tick sums over partitions in a fixed order.
 
 A lane's bits must not depend on how many lanes share its launch (one-lane
-``api.run`` vs a sweep group, a fleet wave of bucket 1 vs 64).  The sum is a
+``api.run`` vs a sweep group, a fleet wave of width 2 vs 64).  The sum is a
 left fold in index order: a reduce on XLA:CPU, an explicit chain of adds on
 every other backend, whose lowering is checked here without a chip.
 """

@@ -4,8 +4,9 @@ Property tests (hypothesis, importorskip-guarded like the other suites)
 for the structures the online fleet's bounded-memory claim rests on:
 
 * :class:`repro.fleet.ringbuf.SlotPool` — no slot aliasing (a slot is
-  never live twice), capacity never exceeded, free ring + active set
-  always partition the capacity, release really recycles.
+  never live twice), capacity never exceeded, every allocation takes the
+  lowest free slot, release really recycles, and the wave width covers
+  the highest occupied slot in powers of two.
 * :class:`repro.fleet.aggregates.ExactSum` — exactly rounded and
   order-independent (the bit-equality mechanism for online totals).
 * :class:`repro.fleet.aggregates.QuantileSketch` — quantiles within the
@@ -20,7 +21,7 @@ import pytest
 
 from repro.core import tickstate
 from repro.fleet.aggregates import ExactSum, QuantileSketch
-from repro.fleet.ringbuf import SlotPool
+from repro.fleet.ringbuf import SlotPool, wave_width
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -50,9 +51,10 @@ LAY = tickstate.TickLayout(2)
        ops=st.lists(st.integers(0, 2 ** 30), min_size=1, max_size=120))
 def test_slot_pool_invariants(capacity, ops):
     """Random alloc/release interleavings: no aliasing, no over-capacity,
-    free+active always partition range(capacity)."""
+    lowest free slot first, width covering the highest occupied slot."""
     pool = SlotPool(capacity, LAY)
     live = set()
+    ever = set()
     for op in ops:
         if op % 2 == 0 or not live:           # alloc
             slot = pool.alloc()
@@ -60,9 +62,10 @@ def test_slot_pool_invariants(capacity, ops):
                 assert slot is None            # capacity never exceeded
             else:
                 assert slot is not None and slot not in live  # no aliasing
-                assert 0 <= slot < capacity
+                assert slot == min(set(range(capacity)) - live)
                 pool.f32[slot, 0] = 1.0        # mark: release must zero it
                 live.add(slot)
+                ever.add(slot)
         else:                                  # release a random live slot
             slot = sorted(live)[op % len(live)]
             pool.release(slot)
@@ -70,10 +73,14 @@ def test_slot_pool_invariants(capacity, ops):
             assert pool.f32[slot].sum() == 0.0  # zeroed on retire
         assert pool.in_flight == len(live)
         assert set(pool.active_slots().tolist()) == live
+        w = pool.width()
+        top = max(live) + 1 if live else 0
+        assert w >= max(top, 2) and w & (w - 1) == 0   # a power of two
+        assert w < 2 * max(top, 2)                     # ... the smallest
+        assert w <= len(pool.f32)                      # ... and it fits
     assert pool.peak_in_flight <= capacity
     # total recycles = allocations beyond the first use of each slot
-    assert pool.recycled == max(pool.total_allocs - capacity, 0) or \
-        pool.total_allocs <= capacity
+    assert pool.recycled == pool.total_allocs - len(ever)
 
 
 def test_slot_pool_release_inactive_raises():
@@ -82,16 +89,28 @@ def test_slot_pool_release_inactive_raises():
         pool.release(0)
 
 
-def test_slot_pool_fifo_recycling():
-    """Freed slots are reused oldest-first (deterministic layout)."""
-    pool = SlotPool(3, LAY)
-    a, b, c = pool.alloc(), pool.alloc(), pool.alloc()
+def test_slot_pool_lowest_free_slot_and_packed_width():
+    """Freed slots are reused lowest-first, so occupied slots stay packed
+    and the wave width falls back as soon as the high slots free."""
+    pool = SlotPool(6, LAY)
+    assert len(pool.f32) == 8                  # room for the widest wave
+    assert pool.width() == 2                   # an empty pool still runs 2
+    a, b, c, d, e = (pool.alloc() for _ in range(5))
+    assert (a, b, c, d, e) == (0, 1, 2, 3, 4)
+    assert pool.width() == 8
     pool.release(b)
     pool.release(a)
-    assert pool.alloc() == b                   # freed first, reused first
-    assert pool.alloc() == a
-    assert pool.alloc() is None
-    assert (a, b, c) == (0, 1, 2)
+    assert pool.width() == 8                   # slot 4 holds the width up
+    assert pool.alloc() == a                   # lowest free, not oldest
+    assert pool.alloc() == b
+    for s in (c, d, e):
+        pool.release(s)
+    assert pool.width() == 2                   # slots 0 and 1 remain
+    assert pool.recycled == 2
+    assert [wave_width(n) for n in (0, 1, 2, 3, 5, 8, 9)] == \
+        [2, 2, 2, 4, 8, 8, 16]
+    assert wave_width(5, 4) == 8 and wave_width(3, 3) == 6  # whole shards
+    assert len(SlotPool(6, LAY, multiple=3).f32) == 9
 
 
 # ------------------------------------------------------------- ExactSum --

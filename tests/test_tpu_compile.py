@@ -143,7 +143,7 @@ def test_sharded_sweep_runner_compiles_on_four_chips(topo):
 
 def _fleet_pool():
     """One slot pool of the chip run's fleet phase: its wave-runner key
-    and the whole-capacity rows the runner takes."""
+    and the rows of its widest wave (the whole pool, at this capacity)."""
     trace, hosts = fleet_bench.build(smoke=False)
     capacity = sum(h.slots for h in hosts)
     cfg = OnlineConfig(wave_s=chip_smoke.WAVE_S, dt=chip_smoke.DT,
